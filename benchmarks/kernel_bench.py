@@ -30,7 +30,8 @@ def all_rows(fast: bool = False):
     q, k, v = arr(B, H, T, hd), arr(B, Hkv, T, hd), arr(B, Hkv, T, hd)
     ref_attn = jax.jit(lambda q, k, v: ref.attention(q, k, v))
     us = _time(ref_attn, q, k, v)
-    out = ops.flash_attention(q, k, v, block_q=128, block_k=128)
+    out = ops.flash_attention(q, k, v, block_q=128, block_k=128,
+                              interpret=True)
     err = float(jnp.max(jnp.abs(out - ref.attention(q, k, v))))
     rows.append(("kernel_flash_attention_ref_xla", us, round(err, 6)))
 
@@ -39,7 +40,8 @@ def all_rows(fast: bool = False):
     lengths = jnp.full((B,), S, jnp.int32)
     ref_dec = jax.jit(ref.decode_attention)
     us = _time(ref_dec, q1, k1, v1, lengths)
-    out = ops.decode_attention(q1, k1, v1, lengths, block_k=128)
+    out = ops.decode_attention(q1, k1, v1, lengths, block_k=128,
+                               interpret=True)
     err = float(jnp.max(jnp.abs(out - ref.decode_attention(q1, k1, v1,
                                                            lengths))))
     rows.append(("kernel_decode_attention_ref_xla", us, round(err, 6)))
@@ -48,7 +50,8 @@ def all_rows(fast: bool = False):
     x, w = arr(E, C, D), arr(E, D, F)
     ref_gmm = jax.jit(ref.moe_gmm)
     us = _time(ref_gmm, x, w)
-    out = ops.moe_gmm(x, w, block_c=64, block_f=64, block_d=64)
+    out = ops.moe_gmm(x, w, block_c=64, block_f=64, block_d=64,
+                      interpret=True)
     err = float(jnp.max(jnp.abs(out - ref.moe_gmm(x, w))))
     rows.append(("kernel_moe_gmm_ref_xla", us, round(err, 5)))
 
@@ -59,7 +62,7 @@ def all_rows(fast: bool = False):
         u = arr(H2, M) * 0.1
         ref_rwkv = jax.jit(ref.rwkv_scan)
         us = _time(ref_rwkv, r, k2, v2, logw, u)
-        o, _ = ops.rwkv_scan(r, k2, v2, logw, u, chunk=32)
+        o, _ = ops.rwkv_scan(r, k2, v2, logw, u, chunk=32, interpret=True)
         oe, _ = ref.rwkv_scan(r, k2, v2, logw, u)
         rows.append(("kernel_rwkv_scan_ref_xla", us,
                      round(float(jnp.max(jnp.abs(o - oe))), 6)))
@@ -68,7 +71,7 @@ def all_rows(fast: bool = False):
         b = arr(2, 256, 128)
         ref_lru = jax.jit(ref.rglru_scan)
         us = _time(ref_lru, a, b)
-        h = ops.rglru_scan(a, b, chunk=64, block_d=64)
+        h = ops.rglru_scan(a, b, chunk=64, block_d=64, interpret=True)
         rows.append(("kernel_rglru_scan_ref_xla", us,
                      round(float(jnp.max(jnp.abs(h - ref.rglru_scan(a, b)))),
                            6)))

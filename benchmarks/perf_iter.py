@@ -1,16 +1,16 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_FLAGS")
-                           or "--xla_force_host_platform_device_count=512")
-
 """§Perf iteration driver: lower one cell with config overrides, print the
 three roofline terms and the delta vs the stored baseline artifact.
 
     PYTHONPATH=src python -m benchmarks.perf_iter --arch granite-34b \
         --shape decode_32k --set decode_shard_s=true [--save tag]
+
+Like ``repro.launch.dryrun``, ``main`` stands 512 host devices in for the
+production mesh (``DRYRUN_XLA_FLAGS`` overrides) before JAX starts.
 """
 
 import argparse
 import json
+import os
 from pathlib import Path
 
 
@@ -27,6 +27,8 @@ def parse_val(v: str):
 
 
 def main():
+    os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_FLAGS")
+                               or "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

@@ -26,6 +26,7 @@ one quantization step of noise — the same trade the wire compression makes.
 from __future__ import annotations
 
 import json
+import weakref
 from pathlib import Path
 from typing import Any
 
@@ -123,7 +124,7 @@ class CheckpointManager:
                  every_n_epochs: int = 1, keep: int = 3,
                  quantize: bool = False):
         self.dir = Path(directory)
-        self.state = state
+        self._state = weakref.ref(state)   # weak: see ReplicaSlot
         self.every = every_n_epochs
         self.keep = keep
         self.quantize = quantize           # int8 on disk, exact manifest
@@ -141,6 +142,10 @@ class CheckpointManager:
             _, old = self.saved.pop(0)
             for suffix in (".npz", ".json"):
                 Path(str(old) + suffix).unlink(missing_ok=True)
+
+    @property
+    def state(self) -> OwnedState:
+        return self._state()
 
     def latest(self) -> tuple[int, Path] | None:
         return self.saved[-1] if self.saved else None

@@ -23,6 +23,7 @@ at the borrow drop (ownership-transfer point), exactly §4.2.3.
 from __future__ import annotations
 
 import itertools
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -222,16 +223,26 @@ class ReplicaSlot:
     """§4.2.3 for pytrees: a backup copy refreshed once per write epoch."""
 
     def __init__(self, state: OwnedState):
-        self.state = state
+        # weak: the state's epoch hook already holds the slot, and a strong
+        # back-reference would make a cycle that keeps the state and the
+        # backup on the device until a cyclic garbage collection
+        self._state = weakref.ref(state)
         self.backup: tuple[int, Any] | None = None
         self.flushes = 0
         state.on_epoch.append(self._flush)
+
+    @property
+    def state(self) -> OwnedState:
+        return self._state()
 
     def _flush(self, addr: ColoredAddr, tree: Any) -> None:
         # Batched write-back: one snapshot per epoch, at the visibility
         # point.  Must be a real copy: the live buffers are donated into the
         # next step (aliasing them would hand the backup to the optimizer).
+        # The previous epoch's snapshot is released before the copy is made,
+        # so the device holds two copies of the state at the flush, not three.
         import jax.numpy as jnp
+        self.backup = None
         self.backup = (addr.color, jax.tree.map(jnp.copy, tree))
         self.flushes += 1
 

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -100,5 +100,5 @@ def pipeline_apply(fn, mesh, stage_params, x, n_microbatches: int = 1,
         return jax.lax.psum(outs, axis)
 
     y = shard_map(run, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
-                  check_rep=False)(stage_params, xm)
+                  check_vma=False)(stage_params, xm)
     return y.reshape(B, *y.shape[2:])

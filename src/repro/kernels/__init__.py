@@ -2,8 +2,9 @@
 
 Each kernel has: ``<name>.py`` (pl.pallas_call + explicit BlockSpec VMEM
 tiling), a jit'd wrapper in ``ops.py``, and a pure-jnp oracle in ``ref.py``.
-On non-TPU backends the wrappers run in interpret mode (correctness only);
-the blocked dataflow is identical to what the MXU executes.
+Off the TPU a wrapper runs only when its caller passes ``interpret=True``
+(correctness only); the blocked dataflow is identical to what the MXU
+executes.
 
 Kernels:
   flash_attention  — causal GQA attention, online softmax over KV blocks

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -87,7 +85,7 @@ def decode_attention(q, k, v, lengths, *, block_k: int = 512,
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, qg, k, v)

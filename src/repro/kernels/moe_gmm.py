@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _kernel(x_ref, w_ref, y_ref, acc_ref):
     d = pl.program_id(3)
@@ -57,7 +55,7 @@ def moe_gmm(x, w, *, block_c: int = 256, block_f: int = 256,
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, i, j, d: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for every kernel.
 
-On TPU the Pallas kernels compile natively; elsewhere ``interpret=True``
-executes the same blocked dataflow in Python (correctness validation — the
-per-kernel tests sweep shapes/dtypes against the ``ref`` oracles).
+The Pallas kernels compile natively for the TPU.  ``interpret=True``
+executes the same blocked dataflow in Python on any backend; only a caller
+that asks for it gets it (the per-kernel tests sweep shapes/dtypes against
+the ``ref`` oracles that way).  Without it, a non-TPU backend raises.
 """
 
 from __future__ import annotations
@@ -18,36 +19,35 @@ from .rglru_scan import rglru_scan as _rglru
 from .rwkv_scan import rwkv_scan as _rwkv
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
+@functools.partial(jax.jit,
+                   static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
-                    block_k: int = 512):
+                    block_k: int = 512, interpret: bool = False):
     return _flash(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-                  interpret=_interpret())
+                  interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("block_k",))
-def decode_attention(q, k, v, lengths, block_k: int = 512):
-    return _decode(q, k, v, lengths, block_k=block_k,
-                   interpret=_interpret())
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+def decode_attention(q, k, v, lengths, block_k: int = 512,
+                     interpret: bool = False):
+    return _decode(q, k, v, lengths, block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_c", "block_f", "block_d"))
-def moe_gmm(x, w, block_c: int = 256, block_f: int = 256, block_d: int = 512):
+                   static_argnames=("block_c", "block_f", "block_d",
+                                    "interpret"))
+def moe_gmm(x, w, block_c: int = 256, block_f: int = 256, block_d: int = 512,
+            interpret: bool = False):
     return _gmm(x, w, block_c=block_c, block_f=block_f, block_d=block_d,
-                interpret=_interpret())
+                interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def rwkv_scan(r, k, v, logw, u, chunk: int = 128):
-    return _rwkv(r, k, v, logw, u, chunk=chunk, interpret=_interpret())
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def rwkv_scan(r, k, v, logw, u, chunk: int = 128, interpret: bool = False):
+    return _rwkv(r, k, v, logw, u, chunk=chunk, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "block_d"))
-def rglru_scan(a, b, chunk: int = 256, block_d: int = 512):
-    return _rglru(a, b, chunk=chunk, block_d=block_d,
-                  interpret=_interpret())
+@functools.partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
+def rglru_scan(a, b, chunk: int = 256, block_d: int = 512,
+               interpret: bool = False):
+    return _rglru(a, b, chunk=chunk, block_d=block_d, interpret=interpret)

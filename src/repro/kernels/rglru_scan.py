@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _kernel(a_ref, b_ref, h_ref, carry_ref, *, chunk: int):
     t = pl.program_id(2)
@@ -28,12 +26,12 @@ def _kernel(a_ref, b_ref, h_ref, carry_ref, *, chunk: int):
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    a = a_ref[0].astype(jnp.float32)            # (C, bd)
-    b = b_ref[0].astype(jnp.float32)
-
-    def step(i, carry):
-        h = a[i] * carry + b[i]
-        h_ref[0, i, :] = h.astype(h_ref.dtype)
+    def step(i, carry):                         # carry: (1, bd)
+        row = pl.ds(i, 1)                       # row i of the (C, bd) tile
+        a = a_ref[0, row, :].astype(jnp.float32)
+        b = b_ref[0, row, :].astype(jnp.float32)
+        h = a * carry + b
+        h_ref[0, row, :] = h.astype(h_ref.dtype)
         return h
 
     carry_ref[...] = jax.lax.fori_loop(0, chunk, step, carry_ref[...])
@@ -56,8 +54,8 @@ def rglru_scan(a, b, *, chunk: int = 256, block_d: int = 512,
         ],
         out_specs=pl.BlockSpec((1, chunk, bd), lambda bi, di, ti: (bi, ti, di)),
         out_shape=jax.ShapeDtypeStruct((B, T, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bd,), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, sout_ref, s_ref, *,
             chunk: int):
@@ -34,21 +32,28 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, sout_ref, s_ref, *,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lw = lw_ref[0].astype(jnp.float32)          # logw <= 0
-    u = u_ref[0].astype(jnp.float32)            # (M,)
+    u = u_ref[0].astype(jnp.float32)            # (1, M)
 
-    cs = jnp.cumsum(lw, axis=0)                 # logA_t (inclusive)
+    hi = jax.lax.Precision.HIGHEST
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # the TPU lowering has no cumsum: an inclusive prefix sum over time is
+    # a matmul with the lower-triangular ones matrix
+    cs = jnp.dot((si <= ti).astype(jnp.float32), lw, precision=hi)  # logA_t
     q_in = r * jnp.exp(cs - lw)                 # r * A_{t-1}   (<= |r|)
     k_in = k * jnp.exp(-cs)                     # bounded by exp(C*decay_max)
     scores = jax.lax.dot_general(q_in, k_in, (((1,), (1,)), ((), ())))
-    ti = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(si < ti, scores, 0.0)    # strict lower triangle
-    diag = jnp.sum(r * u[None, :] * k, axis=1)  # bonus (s == t)
-    o = scores @ v + diag[:, None] * v
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)  # bonus (s == t)
+    o = scores @ v + diag * v
     o = o + q_in @ s_ref[...]                   # cross-chunk history
 
     a_tail = jnp.exp(cs[-1:, :] - cs)           # prod_{s>t} w_s
-    s_ref[...] = (jnp.exp(cs[-1])[:, None] * s_ref[...]
+    # whole-chunk decay of S's row i, broadcast along its columns:
+    # [i, j] = sum_t logw[t, i]
+    decay = jax.lax.dot_general(lw, jnp.ones_like(lw),
+                                (((0,), (0,)), ((), ())), precision=hi)
+    s_ref[...] = (jnp.exp(decay) * s_ref[...]
                   + jax.lax.dot_general(k * a_tail, v,
                                         (((0,), (0,)), ((), ()))))
     o_ref[0] = o.astype(o_ref.dtype)
@@ -66,7 +71,9 @@ def rwkv_scan(r, k, v, logw, u, *, chunk: int = 128,
     BH = B * H
     shp = (BH, T, M)
     rf, kf, vf, lwf = (a.reshape(shp) for a in (r, k, v, logw))
-    uf = jnp.broadcast_to(u[None], (B, H, M)).reshape(BH, M)
+    # (BH, 1, M): a (1, 1, M) block spans the array's last two dims, as the
+    # TPU lowering requires of a block that is not (8, 128)-aligned
+    uf = jnp.broadcast_to(u[None], (B, H, M)).reshape(BH, 1, M)
     grid = (BH, T // chunk)
 
     o, s = pl.pallas_call(
@@ -77,7 +84,7 @@ def rwkv_scan(r, k, v, logw, u, *, chunk: int = 128,
             pl.BlockSpec((1, chunk, M), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, M), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, M), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, M), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, M), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, M), lambda b, c: (b, c, 0)),
@@ -88,7 +95,7 @@ def rwkv_scan(r, k, v, logw, u, *, chunk: int = 128,
             jax.ShapeDtypeStruct((BH, M, M), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((M, M), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, lwf, uf)

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_FLAGS")
-                           or "--xla_force_host_platform_device_count=512")
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract the roofline inputs.
 
@@ -14,10 +10,15 @@ the 16x16 (or 2x16x16) mesh, and records:
   * compiled.memory_analysis()  -> per-device bytes (proves it fits)
   * compiled.cost_analysis()    -> HLO flops / bytes for the roofline
   * collective bytes by op kind -> parsed from the partitioned HLO
+
+The production meshes are stood in for by 512 host devices
+(``DRYRUN_XLA_FLAGS`` overrides the XLA flags); ``main`` sets them before
+JAX starts its backend, and importing this module changes nothing.
 """
 
 import argparse
 import json
+import os
 import re
 import time
 from pathlib import Path
@@ -126,6 +127,39 @@ def _cost_of(lowered_or_compiled) -> dict:
         return {}
 
 
+def sharded_train_step(cfg, opt, mesh, params_abs, batch_abs, *,
+                       microbatches: int = 1):
+    """The train step jitted with the mesh's shardings: parameters by
+    ``param_specs``, optimizer state tied to them by ``opt_state_specs``,
+    inputs by ``batch_specs``; (params, opt_state) are donated.  The caller
+    installs ``mesh`` with ``set_mesh`` before the first call traces it.
+
+    Returns (jitted step, abstract optimizer state, (param, opt_state,
+    batch) NamedSharding trees).
+    """
+    import functools
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.dist.sharding import batch_specs, opt_state_specs, param_specs
+    from repro.train.optimizer import init_opt_state
+    from repro.train.train_step import make_train_step
+
+    ns = lambda spec: NamedSharding(mesh, spec)
+    opt_abs = jax.eval_shape(functools.partial(init_opt_state, opt),
+                             params_abs)
+    p_shard = jax.tree.map(ns, param_specs(mesh, params_abs))
+    o_shard = jax.tree.map(ns, opt_state_specs(mesh, opt_abs, params_abs),
+                           is_leaf=lambda x: isinstance(x, PartitionSpec))
+    b_shard = jax.tree.map(ns, batch_specs(mesh, batch_abs))
+    fn = make_train_step(cfg, opt, mesh=mesh, microbatches=microbatches)
+    jitted = jax.jit(fn, in_shardings=(p_shard, o_shard, b_shard),
+                     out_shardings=(p_shard, o_shard, None),
+                     donate_argnums=(0, 1))
+    return jitted, opt_abs, (p_shard, o_shard, b_shard)
+
+
 def lower_cell(arch: str, shape_name: str, mesh, *, opt_name: str | None = None,
                smoke: bool = False, compile_: bool = True,
                microbatches: int = 1, verbose: bool = True,
@@ -169,21 +203,13 @@ def lower_cell(arch: str, shape_name: str, mesh, *, opt_name: str | None = None,
         """Lower one variant; returns (lowered, abstract param tree)."""
         params_abs = jax.eval_shape(functools.partial(init_params, cfg2),
                                     jax.random.PRNGKey(0))
+        if mode == "train":
+            jitted, opt_abs, _ = sharded_train_step(
+                cfg2, opt, mesh, params_abs, batch_abs,
+                microbatches=microbatches)
+            return jitted.lower(params_abs, opt_abs, batch_abs), params_abs
         p_shard = jax.tree.map(ns, param_specs(mesh, params_abs))
         b_shard = jax.tree.map(ns, batch_specs(mesh, batch_abs))
-        if mode == "train":
-            opt_abs = jax.eval_shape(functools.partial(init_opt_state, opt),
-                                     params_abs)
-            from repro.dist.sharding import opt_state_specs
-            o_shard = jax.tree.map(ns, opt_state_specs(mesh, opt_abs,
-                                                       params_abs),
-                                   is_leaf=is_spec)
-            fn = make_train_step(cfg2, opt, mesh=mesh,
-                                 microbatches=microbatches)
-            jitted = jax.jit(fn, in_shardings=(p_shard, o_shard, b_shard),
-                             out_shardings=(p_shard, o_shard, None),
-                             donate_argnums=(0, 1))
-            return jitted.lower(params_abs, opt_abs, batch_abs), params_abs
         if mode == "prefill":
             from repro.serve.serve_step import make_prefill
             fn = make_prefill(cfg2, mesh=mesh)
@@ -291,6 +317,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *, opt_name: str | None = None,
 
 
 def main():
+    os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_FLAGS")
+                               or "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -18,10 +18,8 @@ while the membership changes, DESIGN §2.2):
   keeps allocating on the new member.
 """
 
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
+import os
 import tempfile
 
 import jax
@@ -128,6 +126,10 @@ def run_protocol(n_servers: int = 4, verbose: bool = True) -> bool:
 
 
 def main():
+    # the reshard meshes need 8 devices: host devices stand in for them
+    # (set before JAX starts its backend; an XLA_FLAGS already given wins)
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--from-mesh", default="2x4")

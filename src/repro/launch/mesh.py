@@ -1,31 +1,25 @@
 """Production mesh construction.
 
 A function, not a module-level constant: importing this module must never
-touch jax device state (the dry-run sets XLA_FLAGS before any jax init).
-
-``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types`` kwarg) only
-exist in newer JAX releases; older installs get plain meshes.
+touch jax device state (a caller may still set XLA_FLAGS before jax
+initializes its backend).
 """
 
 from __future__ import annotations
 
 import jax
-
-
-def _axis_kw(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, smoke dry-runs on few host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_kw(len(axes)))
+    """Arbitrary mesh (tests, smoke dry-runs on few host devices, the
+    chips of one host)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

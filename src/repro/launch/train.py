@@ -1,12 +1,13 @@
 """End-to-end training driver.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
-        [--smoke] [--steps 50] [--batch 8] [--seq 256] [--ckpt-dir DIR] \
+        [--full] [--steps 50] [--batch 8] [--seq 256] [--ckpt-dir DIR] \
         [--fail-at N]   (inject a failure: restore from the epoch backup)
 
 Runs the real loop: synthetic data -> ownership-wrapped train state ->
 jitted step (donated buffers, color bump per epoch) -> epoch-batched
-checkpointing -> optional failure injection + recovery.
+checkpointing -> optional failure injection + recovery.  The default is
+the reduced smoke config; ``--full`` trains the published widths.
 """
 
 from __future__ import annotations
@@ -18,11 +19,11 @@ import jax
 import numpy as np
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--smoke", action="store_true", default=True)
-    ap.add_argument("--full", dest="smoke", action="store_false")
+    ap.add_argument("--full", action="store_true",
+                    help="the published widths, not the smoke config")
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
@@ -31,14 +32,19 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--fail-at", type=int, default=0)
     ap.add_argument("--lr", type=float, default=3e-3)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> dict:
+    """Train; returns the per-step losses and wall seconds.  Each step's
+    time ends when its loss reaches the host, so the first one includes
+    the compile."""
     from repro import configs
     from repro.checkpoint import CheckpointManager
     from repro.models import init_params
     from repro.train import OptConfig, TrainState, synthetic_batches
 
-    cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
+    cfg = configs.get(args.arch) if args.full else configs.smoke(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))
     n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
     print(f"arch={cfg.name} params={n_params/1e6:.2f}M "
@@ -54,16 +60,16 @@ def main():
 
     data = synthetic_batches(cfg.vocab, args.batch, args.seq,
                              prefix_len=cfg.prefix_len, d_model=cfg.d_model)
-    losses = []
-    t0 = time.time()
+    losses, step_s = [], []
     for step in range(1, args.steps + 1):
         batch = jax.tree.map(jax.numpy.asarray, next(data))
+        t0 = time.perf_counter()
         m = ts.step(batch)
         losses.append(float(m["loss"]))
+        step_s.append(time.perf_counter() - t0)
         if step % 5 == 0 or step == 1:
-            dt = (time.time() - t0) / step
             print(f"step {step:4d} loss {losses[-1]:.4f} "
-                  f"color {ts.color} {dt*1e3:.0f} ms/step")
+                  f"color {ts.color} {step_s[-1]*1e3:.0f} ms")
         if args.fail_at and step == args.fail_at:
             print(f"!! injecting failure at step {step}; promoting backup")
             ts.restore_from_backup()
@@ -72,7 +78,14 @@ def main():
           f"({'improved' if losses[-1] < losses[0] else 'NO IMPROVEMENT'})")
     if mgr and mgr.latest():
         print(f"checkpoints: {len(mgr.saved)}, latest color {mgr.latest()[0]}")
-    return losses
+    return {"arch": cfg.name, "losses": losses, "step_s": step_s}
+
+
+def main(argv=None) -> dict:
+    from repro.launch.compile_cache import enable_compile_cache
+    args = parse_args(argv)
+    enable_compile_cache()
+    return run(args)
 
 
 if __name__ == "__main__":

@@ -45,7 +45,7 @@ class ModelConfig:
     remat: bool = True
     scan_layers: bool = True
     attn_chunk: int = 1024           # kv-block size for the chunked XLA path
-    attn_impl: str = "xla"           # xla | pallas (pallas: TPU, interpret on CPU)
+    attn_impl: str = "xla"           # xla | pallas (nothing reads it yet)
     max_target_len: int = 8192       # serving cache default
     unroll_chunks: bool = False      # rwkv: python loop (flops calibration)
     unroll_experts: bool = False     # moe: python loop (flops calibration)

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import layers as L
@@ -87,7 +87,7 @@ def attn_decode_sharded(cfg: ModelConfig, mesh, p, x, positions, cache,
         body, mesh=mesh,
         in_specs=(rep4, rep4, rep4, shard4, shard4, P()),
         out_specs=(rep4, shard4, shard4),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, cache["k"], cache["v"], length)
     y = jnp.einsum("btnh,nhd->btd", out, p["wo"])
     return y, {"k": kc, "v": vc}

@@ -113,7 +113,7 @@ def moe_shardmap(cfg: ModelConfig, mesh, p, x):
     from (full-T gather + psum) to 2 x (tokens*k*cap/ranks) per device
     (the paper's spawn_to: computation moves to the data owner)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
     n_model = mesh.shape["model"]
@@ -134,7 +134,7 @@ def moe_shardmap(cfg: ModelConfig, mesh, p, x):
         return shard_map(inner_a2a, mesh=mesh,
                          in_specs=(pspec_p, pspec_x),
                          out_specs=(pspec_x, P()),
-                         check_rep=False)(p, x)
+                         check_vma=False)(p, x)
 
     def inner(p_loc, x_loc):
         y, aux = moe_block(cfg, p_loc, x_loc, axis_name="model",
@@ -146,7 +146,7 @@ def moe_shardmap(cfg: ModelConfig, mesh, p, x):
         inner, mesh=mesh,
         in_specs=(pspec_p, pspec_x),
         out_specs=(pspec_x, P()),
-        check_rep=False,
+        check_vma=False,
     )(p, x)
     return y, aux
 

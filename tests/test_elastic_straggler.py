@@ -118,7 +118,7 @@ def test_grow_after_shrink_controller_uses_new_server():
 
 def test_elastic_reshard_subprocess():
     """Checkpoint on a 2x4 mesh, restore onto 4x2 and 8x1."""
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     cwd = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for to in ("4x2", "8x1"):
         out = subprocess.run(

@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles,
-in interpret mode (the TPU dataflow executed in Python)."""
+in interpret mode (the TPU dataflow executed in Python), which every call
+here asks for explicitly."""
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,8 @@ def test_flash_attention(B, H, Hkv, T, hd, bq, bk, dtype):
     q = arr(B, H, T, hd, dtype=dtype)
     k = arr(B, Hkv, T, hd, dtype=dtype)
     v = arr(B, Hkv, T, hd, dtype=dtype)
-    out = ops.flash_attention(q, k, v, block_q=bq, block_k=bk)
+    out = ops.flash_attention(q, k, v, block_q=bq, block_k=bk,
+                              interpret=True)
     exp = ref.attention(q, k, v)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -37,7 +39,8 @@ def test_flash_attention(B, H, Hkv, T, hd, bq, bk, dtype):
 
 def test_flash_attention_noncausal():
     q, k, v = arr(1, 2, 64, 64), arr(1, 2, 64, 64), arr(1, 2, 64, 64)
-    out = ops.flash_attention(q, k, v, causal=False, block_q=32, block_k=32)
+    out = ops.flash_attention(q, k, v, causal=False, block_q=32, block_k=32,
+                              interpret=True)
     exp = ref.attention(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=2e-3, atol=2e-3)
@@ -53,7 +56,8 @@ def test_decode_attention(B, H, Hkv, S, hd):
     k = arr(B, Hkv, S, hd)
     v = arr(B, Hkv, S, hd)
     lengths = jnp.asarray(RNG.integers(1, S + 1, B), jnp.int32)
-    out = ops.decode_attention(q, k, v, lengths, block_k=128)
+    out = ops.decode_attention(q, k, v, lengths, block_k=128,
+                               interpret=True)
     exp = ref.decode_attention(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=2e-3, atol=2e-3)
@@ -65,7 +69,8 @@ def test_decode_attention(B, H, Hkv, S, hd):
 def test_moe_gmm_property(e, c, d, f):
     x = arr(e, c, d)
     w = arr(e, d, f)
-    out = ops.moe_gmm(x, w, block_c=64, block_f=64, block_d=64)
+    out = ops.moe_gmm(x, w, block_c=64, block_f=64, block_d=64,
+                      interpret=True)
     exp = ref.moe_gmm(x, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=2e-3, atol=2e-2)
@@ -75,7 +80,8 @@ def test_moe_gmm_property(e, c, d, f):
 def test_moe_gmm_dtypes(dtype):
     x = arr(2, 128, 128, dtype=dtype)
     w = arr(2, 128, 128, dtype=dtype)
-    out = ops.moe_gmm(x, w, block_c=64, block_f=64, block_d=64)
+    out = ops.moe_gmm(x, w, block_c=64, block_f=64, block_d=64,
+                      interpret=True)
     exp = ref.moe_gmm(x, w)
     tol = 5e-2 if dtype == jnp.bfloat16 else 2e-3
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -92,7 +98,7 @@ def test_rwkv_scan(B, H, T, M, chunk):
     r, k, v = arr(B, H, T, M), arr(B, H, T, M), arr(B, H, T, M)
     logw = -0.105 * jax.nn.sigmoid(arr(B, H, T, M))
     u = arr(H, M, scale=0.1)
-    o, S = ops.rwkv_scan(r, k, v, logw, u, chunk=chunk)
+    o, S = ops.rwkv_scan(r, k, v, logw, u, chunk=chunk, interpret=True)
     oe, Se = ref.rwkv_scan(r, k, v, logw, u)
     np.testing.assert_allclose(np.asarray(o), np.asarray(oe),
                                rtol=2e-3, atol=2e-3)
@@ -108,7 +114,7 @@ def test_rwkv_scan(B, H, T, M, chunk):
 def test_rglru_scan(B, T, D, chunk, bd):
     a = jax.nn.sigmoid(arr(B, T, D))
     b = arr(B, T, D)
-    h = ops.rglru_scan(a, b, chunk=chunk, block_d=bd)
+    h = ops.rglru_scan(a, b, chunk=chunk, block_d=bd, interpret=True)
     he = ref.rglru_scan(a, b)
     np.testing.assert_allclose(np.asarray(h), np.asarray(he),
                                rtol=2e-3, atol=2e-3)
@@ -119,7 +125,25 @@ def test_rglru_scan_strong_decay_stability():
     B, T, D = 1, 128, 32
     a = jnp.full((B, T, D), 1e-4, jnp.float32)
     b = arr(B, T, D)
-    h = ops.rglru_scan(a, b, chunk=32, block_d=32)
+    h = ops.rglru_scan(a, b, chunk=32, block_d=32, interpret=True)
     assert np.isfinite(np.asarray(h)).all()
     np.testing.assert_allclose(np.asarray(h), np.asarray(ref.rglru_scan(a, b)),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("name,args", [
+    ("flash_attention", lambda: (arr(1, 2, 64, 64),) * 3),
+    ("decode_attention", lambda: (arr(1, 2, 64), arr(1, 2, 128, 64),
+                                  arr(1, 2, 128, 64),
+                                  jnp.full((1,), 128, jnp.int32))),
+    ("moe_gmm", lambda: (arr(1, 128, 128), arr(1, 128, 128))),
+    ("rwkv_scan", lambda: (arr(1, 1, 64, 16),) * 4 + (arr(1, 16),)),
+    ("rglru_scan", lambda: (arr(1, 64, 64), arr(1, 64, 64))),
+])
+def test_no_silent_interpret_fallback(name, args):
+    """Off the TPU a kernel runs only when its caller asks for interpret
+    mode; otherwise the call raises instead of hiding the device."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the kernels compile natively here")
+    with pytest.raises(ValueError, match="interpret"):
+        getattr(ops, name)(*args())

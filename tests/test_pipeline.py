@@ -43,7 +43,7 @@ def test_two_stage_pipeline_subprocess():
                                    rtol=1e-4, atol=1e-5)
         print("PIPELINE_OK")
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=300, env=env,
                          cwd=os.path.dirname(os.path.dirname(

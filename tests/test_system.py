@@ -82,7 +82,7 @@ def test_dryrun_smoke_subprocess():
     import os
     env = dict(os.environ,
                DRYRUN_XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH="src")
+               PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "qwen3-0.6b",
          "--shape", "train_4k", "--mesh", "2x4", "--smoke",

@@ -1,0 +1,140 @@
+"""Compile rehearsals for a described TPU v5e: no chip is attached, but the
+TPU compiler refuses here what the chip would refuse (unaligned blocks,
+primitives Mosaic cannot lower, programs that do not fit HBM).
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.  The persistent compilation cache is off around these
+compiles (an entry compiled for a described chip cannot be read back).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro import configs
+from repro.models import build_batch_spec, init_cache, init_params
+from repro.train import OptConfig, init_opt_state, make_train_step
+
+HBM_BYTES = 16 * 2**30                # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _placed(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _qwen_abstract():
+    cfg = configs.get("qwen3_0_6b")
+    params = jax.eval_shape(functools.partial(init_params, cfg),
+                            jax.random.PRNGKey(0))
+    return cfg, params
+
+
+# (kernel module, argument shapes/dtypes) at the widths of the configs
+# that use each kernel
+_BF16, _F32, _I32 = jnp.bfloat16, jnp.float32, jnp.int32
+KERNELS = {
+    # qwen3-0.6b decode: 4 slots, 16/8 heads, head_dim 128, 8k cache
+    "decode_attention": ([(4, 16, 128), (4, 8, 8192, 128),
+                          (4, 8, 8192, 128)], [_BF16] * 3 + [_I32], (4,)),
+    # qwen3-0.6b prefill/train attention at 2k tokens
+    "flash_attention": ([(1, 16, 2048, 128), (1, 8, 2048, 128),
+                         (1, 8, 2048, 128)], [_BF16] * 3, None),
+    # qwen3-moe-235b expert share: d_model 4096, expert d_ff 1536
+    "moe_gmm": ([(8, 512, 4096), (8, 4096, 1536)], [_BF16] * 2, None),
+    # rwkv6-3b: 40 heads of 64 over 512 tokens
+    "rwkv_scan": ([(1, 40, 512, 64)] * 4 + [(40, 64)], [_BF16] * 5, None),
+    # recurrentgemma-9b: lru width 4096
+    "rglru_scan": ([(1, 512, 4096)] * 2, [_F32] * 2, None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(name, one_chip):
+    import importlib
+    fn = getattr(importlib.import_module(f"repro.kernels.{name}"), name)
+    shapes, dtypes, extra = KERNELS[name]
+    if extra is not None:
+        shapes = shapes + [extra]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in zip(shapes, dtypes)]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen3_decode_step_fits_one_v5e(one_chip):
+    """The engine's decode step at full width (4 slots, max_len 256)."""
+    from repro.serve.serve_step import make_serve_step
+    cfg, params = _qwen_abstract()
+    cache = jax.eval_shape(functools.partial(init_cache, cfg, 4, 256))
+    tokens = jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        _placed(params, one_chip), _placed(cache, one_chip), tokens).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_qwen3_train_step_fits_one_v5e(one_chip):
+    """The launcher's train step at full width (batch 8x256, AdamW with
+    f32 moments), with room for the ReplicaSlot backup it keeps: state +
+    backup + temporaries must fit one chip."""
+    cfg, params = _qwen_abstract()
+    opt = OptConfig()
+    opt_state = jax.eval_shape(functools.partial(init_opt_state, opt), params)
+    batch = build_batch_spec(cfg, 8, 256)
+    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1)).lower(
+        _placed(params, one_chip), _placed(opt_state, one_chip),
+        _placed(batch, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 2 * mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_qwen3_sharded_train_step_compiles_for_2x2(topo):
+    """The (data 2, model 2) train step that ``chip_smoke.py --four-chips``
+    runs: dryrun's shardings, partitioned over four chips."""
+    from repro.dist.sharding import set_mesh
+    from repro.launch.dryrun import sharded_train_step
+    cfg, params = _qwen_abstract()
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    set_mesh(mesh)
+    try:
+        jitted, opt_state, _ = sharded_train_step(
+            cfg, OptConfig(), mesh, params, build_batch_spec(cfg, 8, 256))
+        compiled = jitted.lower(params, opt_state,
+                                build_batch_spec(cfg, 8, 256)).compile()
+    finally:
+        set_mesh(None)
+    mem = compiled.memory_analysis()
+    state_bytes = sum(l.size * l.dtype.itemsize
+                      for l in jax.tree.leaves((params, opt_state)))
+    # every chip holds about a quarter of the state, not all of it
+    assert mem.argument_size_in_bytes < 0.3 * state_bytes
+    assert "all-reduce" in compiled.as_text()

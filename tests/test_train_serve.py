@@ -92,6 +92,55 @@ def test_backup_promotion_restores_epoch():
                                   np.asarray(good, np.float32))
 
 
+def test_replica_slot_drops_old_backup_and_promotes_newest(monkeypatch):
+    """The flush releases the previous snapshot before it copies the new
+    one (two copies of the state on the device at the flush, not three),
+    and promotion still restores the newest epoch."""
+    from repro.core.jaxstate import ReplicaSlot
+    state = OwnedState("t", {"w": jnp.zeros(4)})
+    slot = ReplicaSlot(state)
+    held_during_copy = []
+    real_copy = jnp.copy
+
+    def copy(x):
+        held_during_copy.append(slot.backup)
+        return real_copy(x)
+
+    monkeypatch.setattr(jnp, "copy", copy)
+    for v in (1.0, 2.0, 3.0):
+        state.write({"w": jnp.full(4, v)})
+    assert held_during_copy == [None, None, None]
+    assert slot.flushes == 3 and slot.backup[0] == 3
+    state._tree = {"w": jnp.zeros(4)}       # crash: live buffers lost
+    slot.promote()
+    assert state.color == 3
+    np.testing.assert_array_equal(np.asarray(state.read()["w"]),
+                                  np.full(4, 3.0))
+
+
+def test_dropped_train_state_frees_without_cyclic_gc(tmp_path):
+    """The backup slot and the checkpoint manager hold the state weakly:
+    dropping a TrainState releases its buffers (state and backup) at once,
+    not at some later cyclic garbage collection."""
+    import gc
+    import weakref
+    from repro.checkpoint import CheckpointManager
+    cfg, params, opt = _setup()
+    data = synthetic_batches(cfg.vocab, 4, 32)
+    gc.disable()
+    try:
+        ts = TrainState(cfg, opt, params)
+        slot = ts.replicate()
+        mgr = CheckpointManager(tmp_path, ts.state)
+        ts.step(jax.tree.map(jnp.asarray, next(data)))
+        assert slot.backup is not None and mgr.latest() is not None
+        state, holder = weakref.ref(ts.state), weakref.ref(slot)
+        del ts, slot, mgr
+        assert state() is None and holder() is None    # backup gone too
+    finally:
+        gc.enable()
+
+
 def test_owned_state_borrow_rules():
     s = OwnedState("t", {"w": jnp.zeros(4)})
     r = s.borrow()
