@@ -122,8 +122,9 @@ class StateRef:
 
 
 class StateMutRef:
-    """Exclusive write epoch; color bump + epoch hooks fire on drop.  Use
-    as a scoped guard (``with state.borrow_mut() as m:``) — the same
+    """Exclusive write epoch; color bump + epoch hooks fire on drop, inside
+    an ``ownership.epoch`` profiler span.  Use as a scoped guard
+    (``with state.borrow_mut() as m:``) — the same
     ``value``/``set``/``update`` slot surface as the DSM ``WriteGuard``;
     an exception inside the scope still drops the borrow, and use after
     drop raises ``BorrowError``."""
@@ -168,11 +169,12 @@ class StateMutRef:
             # implements the paper's U-bit dedup — see core.ownership — but a
             # train step IS the epoch boundary here: checkpoints and replica
             # refresh key off it.)
-            o.addr = o.addr.bumped()          # the color bump = invalidation
-            o._u = True
-            o.write_epochs += 1
-            for hook in o.on_epoch:           # batched write-back flush point
-                hook(o.addr, o._tree)
+            with jax.profiler.TraceAnnotation("ownership.epoch"):
+                o.addr = o.addr.bumped()      # the color bump = invalidation
+                o._u = True
+                o.write_epochs += 1
+                for hook in o.on_epoch:       # batched write-back flush point
+                    hook(o.addr, o._tree)
 
     def __enter__(self):
         return self
@@ -220,7 +222,10 @@ class StateCache:
 
 
 class ReplicaSlot:
-    """§4.2.3 for pytrees: a backup copy refreshed once per write epoch."""
+    """§4.2.3 for pytrees: a backup copy refreshed once per write epoch.
+
+    Each flush is a ``replica.flush`` profiler span; while the profiler
+    records, the span carries the bytes it copies as stat ``nbytes``."""
 
     def __init__(self, state: OwnedState):
         # weak: the state's epoch hook already holds the slot, and a strong
@@ -241,9 +246,14 @@ class ReplicaSlot:
         # next step (aliasing them would hand the backup to the optimizer).
         # The previous epoch's snapshot is released before the copy is made,
         # so the device holds two copies of the state at the flush, not three.
+        # The bytes are counted only while the profiler records: with it
+        # off, the span reads no leaf.
         import jax.numpy as jnp
-        self.backup = None
-        self.backup = (addr.color, jax.tree.map(jnp.copy, tree))
+        span = jax.profiler.TraceAnnotation
+        stats = {"nbytes": _tree_bytes(tree)} if span.is_enabled() else {}
+        with span("replica.flush", **stats):
+            self.backup = None
+            self.backup = (addr.color, jax.tree.map(jnp.copy, tree))
         self.flushes += 1
 
     def promote(self) -> Any:

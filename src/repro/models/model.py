@@ -21,9 +21,15 @@ def loss_fn(cfg: ModelConfig, params, batch, mesh=None):
     With ``cfg.chunked_ce = n`` the head matmul + CE run per sequence-chunk
     inside a scan, so the (B,T,V) logits (bf16 *and* the f32 cast) never
     materialize — the §Perf memory-term optimization."""
+    (x, aux), head = T.forward_hidden(cfg, params, batch, mesh=mesh)
+    return _lm_head_loss(cfg, x, head, batch) + 0.01 * aux
+
+
+@jax.named_scope("lm_head_loss")
+def _lm_head_loss(cfg: ModelConfig, x, head, batch):
+    """Mean next-token NLL of the final hidden states through the head."""
     labels = batch["labels"]
     if cfg.chunked_ce:
-        (x, aux), head = T.forward_hidden(cfg, params, batch, mesh=mesh)
         if cfg.prefix_len and "prefix_embeds" in batch:
             x = x[:, -labels.shape[1]:, :]
         B, Tlen, D = x.shape
@@ -40,18 +46,16 @@ def loss_fn(cfg: ModelConfig, params, batch, mesh=None):
         xs = (x.reshape(B, n, C, D).swapaxes(0, 1),
               labels.reshape(B, n, C).swapaxes(0, 1))
         total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), xs)
-        nll = total / (B * Tlen)
-        return nll + 0.01 * aux
+        return total / (B * Tlen)
 
-    logits, aux = forward(cfg, params, batch, mesh=mesh)
+    logits = jnp.einsum("btd,dv->btv", x, head)
     if cfg.prefix_len and "prefix_embeds" in batch:
         logits = logits[:, -labels.shape[1]:, :]       # loss on text positions
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
     true_logit = jnp.take_along_axis(
         logits, labels[..., None], axis=-1)[..., 0]
-    nll = (logz - true_logit).mean()
-    return nll + 0.01 * aux
+    return (logz - true_logit).mean()
 
 
 def build_batch_spec(cfg: ModelConfig, global_batch: int, seq_len: int,

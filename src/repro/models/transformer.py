@@ -191,21 +191,22 @@ def _block(cfg: ModelConfig, p, x, positions, cache, length, layer_idx,
         return x + y2, new_cache, 0.0
 
     if "attn" in p:
-        if cache is not None and cfg.window:
-            y, new_c = _attn_with_ring(cfg, p["attn"], h, positions, cache,
-                                       length)
-        elif (cache is not None and cfg.decode_shard_s
-              and (mesh or current_mesh()) is not None):
-            from .decode_sharded import attn_decode_sharded
-            y, new_c = attn_decode_sharded(cfg, mesh or current_mesh(),
-                                           p["attn"], h, positions, cache,
-                                           length)
-        else:
-            c = None if cache is None else {**cache, "length": length}
-            y, new_c = L.attn_block(cfg, p["attn"], h, positions, cache=c,
-                                    window=cfg.window)
-            if new_c is not None:
-                new_c = {"k": new_c["k"], "v": new_c["v"]}
+        with jax.named_scope("attention"):
+            if cache is not None and cfg.window:
+                y, new_c = _attn_with_ring(cfg, p["attn"], h, positions,
+                                           cache, length)
+            elif (cache is not None and cfg.decode_shard_s
+                  and (mesh or current_mesh()) is not None):
+                from .decode_sharded import attn_decode_sharded
+                y, new_c = attn_decode_sharded(cfg, mesh or current_mesh(),
+                                               p["attn"], h, positions, cache,
+                                               length)
+            else:
+                c = None if cache is None else {**cache, "length": length}
+                y, new_c = L.attn_block(cfg, p["attn"], h, positions,
+                                        cache=c, window=cfg.window)
+                if new_c is not None:
+                    new_c = {"k": new_c["k"], "v": new_c["v"]}
     else:
         y, new_c = RGLRU.rglru_block(cfg, p["rec"], h,
                                      cache if cache is not None else None)
@@ -214,16 +215,17 @@ def _block(cfg: ModelConfig, p, x, positions, cache, length, layer_idx,
 
     h2 = L.rms_norm(x, p["norm2"], cfg.norm_eps)
     aux = 0.0
-    if cfg.n_experts:
-        mesh = mesh or current_mesh()
-        if mesh is not None:
-            y2, aux = MOE.moe_shardmap(cfg, mesh, p["moe"], h2)
+    with jax.named_scope("mlp"):
+        if cfg.n_experts:
+            mesh = mesh or current_mesh()
+            if mesh is not None:
+                y2, aux = MOE.moe_shardmap(cfg, mesh, p["moe"], h2)
+            else:
+                y2, aux = MOE.moe_block(cfg, p["moe"], h2)
+            if cfg.dense_residual:
+                y2 = y2 + L.mlp_block(cfg, p["mlp"], h2)
         else:
-            y2, aux = MOE.moe_block(cfg, p["moe"], h2)
-        if cfg.dense_residual:
-            y2 = y2 + L.mlp_block(cfg, p["mlp"], h2)
-    else:
-        y2 = L.mlp_block(cfg, p["mlp"], h2)
+            y2 = L.mlp_block(cfg, p["mlp"], h2)
     x = x + y2
     x = shard_act(x)
     return x, (new_c if cache is not None else None), aux

@@ -86,6 +86,7 @@ def clip_by_global_norm(tree, max_norm):
     return jax.tree.map(lambda g: (g.astype(jnp.float32) * scale), tree), norm
 
 
+@jax.named_scope("optimizer")
 def apply_updates(cfg: OptConfig, params, grads, state):
     """Returns (new_params, new_state, metrics)."""
     grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)

@@ -65,7 +65,8 @@ def make_train_step(cfg: ModelConfig, opt: OptConfig, mesh=None,
 class TrainState:
     """Host-side ownership wrapper around (params, opt_state).
 
-    Each ``step`` is one write epoch: mutable borrow -> donated update ->
+    Each ``step`` is one write epoch: mutable borrow -> donated update
+    (the call of the jitted step is a ``train.dispatch`` profiler span) ->
     color bump on drop.  ``replicate()`` attaches a §4.2.3 backup slot whose
     write-back is batched per epoch.
     """
@@ -92,7 +93,9 @@ class TrainState:
     def step(self, batch):
         with self.state.borrow_mut() as ref:
             params, opt_state = ref.deref_mut()
-            params, opt_state, metrics = self._step(params, opt_state, batch)
+            with jax.profiler.TraceAnnotation("train.dispatch"):
+                params, opt_state, metrics = self._step(params, opt_state,
+                                                        batch)
             ref.set((params, opt_state))
         self.metrics = metrics
         return metrics
