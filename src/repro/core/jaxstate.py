@@ -8,16 +8,21 @@ refresh, async checkpoint).  ``OwnedState`` applies DRust's protocol to a
 JAX pytree:
 
   * the pytree has a **colored logical address** (name, color);
-  * the writer takes a *mutable borrow* — exclusive, buffers donated into the
-    step function — and the color is bumped when the borrow drops (one bump
-    per write epoch, the U-bit rule);
+  * the writer takes a *mutable borrow* — exclusive — and the color is
+    bumped when the borrow drops (one bump per write epoch, the U-bit rule).
+    JAX arrays are immutable, so the only way a step can overwrite the
+    epoch's buffers is donation, and a step may donate them only while the
+    owner is their sole holder (``OwnedState.holders`` is 0): copy-on-write
+    decided by ownership, with no eager copy;
   * readers take *immutable borrows* keyed by the colored address.  A reader
     whose cache matches the color does **zero communication**; a stale reader
     refetches.  No invalidation traffic exists anywhere.
 
 ``StateCache`` is the per-replica read cache (hashmap H).  ``ReplicaSlot``
 is the fault-tolerance hook: write-backs are batched per epoch and flushed
-at the borrow drop (ownership-transfer point), exactly §4.2.3.
+at the borrow drop (ownership-transfer point), exactly §4.2.3.  The flush
+keeps the epoch's own immutable arrays; it registers as a holder, so no
+step donates them while the slot keeps them.
 """
 
 from __future__ import annotations
@@ -55,6 +60,9 @@ class OwnedState:
         self._live_mut = False
         self._u = False                       # U bit: bumped this epoch?
         self.write_epochs = 0
+        # holders besides the owner that keep the epoch's buffers past it
+        # (a ReplicaSlot): while any is registered no step may donate them
+        self.holders = 0
         self.on_epoch: list[Callable[[ColoredAddr, Any], None]] = []
 
     # ---- immutable borrow -------------------------------------------------
@@ -222,10 +230,16 @@ class StateCache:
 
 
 class ReplicaSlot:
-    """§4.2.3 for pytrees: a backup copy refreshed once per write epoch.
+    """§4.2.3 for pytrees: the backup is the newest write epoch's state.
 
-    Each flush is a ``replica.flush`` profiler span; while the profiler
-    records, the span carries the bytes it copies as stat ``nbytes``."""
+    The slot keeps the very arrays the epoch produced.  JAX arrays are
+    immutable and the slot counts as a holder of the state
+    (``OwnedState.holders``), so no step donates them while it keeps them:
+    the next step writes fresh buffers, and the snapshot stays bit for bit
+    the epoch's without a copy.  Each flush is a ``replica.flush``
+    profiler span; while the profiler records, the span carries stat
+    ``held`` (the bytes of the snapshot it keeps) and stat ``nbytes`` (the
+    bytes it copies: 0)."""
 
     def __init__(self, state: OwnedState):
         # weak: the state's epoch hook already holds the slot, and a strong
@@ -234,6 +248,7 @@ class ReplicaSlot:
         self._state = weakref.ref(state)
         self.backup: tuple[int, Any] | None = None
         self.flushes = 0
+        state.holders += 1
         state.on_epoch.append(self._flush)
 
     @property
@@ -242,18 +257,14 @@ class ReplicaSlot:
 
     def _flush(self, addr: ColoredAddr, tree: Any) -> None:
         # Batched write-back: one snapshot per epoch, at the visibility
-        # point.  Must be a real copy: the live buffers are donated into the
-        # next step (aliasing them would hand the backup to the optimizer).
-        # The previous epoch's snapshot is released before the copy is made,
-        # so the device holds two copies of the state at the flush, not three.
-        # The bytes are counted only while the profiler records: with it
-        # off, the span reads no leaf.
-        import jax.numpy as jnp
+        # point.  The previous snapshot is released and the epoch's own
+        # arrays are kept.  The bytes are counted only while the profiler
+        # records: with it off, the span reads no leaf.
         span = jax.profiler.TraceAnnotation
-        stats = {"nbytes": _tree_bytes(tree)} if span.is_enabled() else {}
+        stats = ({"nbytes": 0, "held": _tree_bytes(tree)}
+                 if span.is_enabled() else {})
         with span("replica.flush", **stats):
-            self.backup = None
-            self.backup = (addr.color, jax.tree.map(jnp.copy, tree))
+            self.backup = (addr.color, tree)
         self.flushes += 1
 
     def promote(self) -> Any:

@@ -5,7 +5,8 @@
         [--fail-at N]   (inject a failure: restore from the epoch backup)
 
 Runs the real loop: synthetic data -> ownership-wrapped train state ->
-jitted step (donated buffers, color bump per epoch) -> epoch-batched
+jitted step (color bump per epoch; the backup slot keeps each epoch's
+arrays, so the step does not donate them) -> epoch-batched
 checkpointing -> optional failure injection + recovery.  The default is
 the reduced smoke config; ``--full`` trains the published widths.
 """

@@ -62,13 +62,30 @@ def make_train_step(cfg: ModelConfig, opt: OptConfig, mesh=None,
     return train_step
 
 
+def _owned_step(fn, state: OwnedState):
+    """``fn`` jitted, donating ``(params, opt_state)`` only while ``state``
+    has no holder besides its owner, else without donation.  Neither
+    variant compiles before its first call.  The closure holds the state,
+    not the ``TrainState``: no reference cycle."""
+    donating = jax.jit(fn, donate_argnums=(0, 1))
+    keeping = jax.jit(fn)
+
+    def step(params, opt_state, batch):
+        return (keeping if state.holders else donating)(params, opt_state,
+                                                        batch)
+    return step
+
+
 class TrainState:
     """Host-side ownership wrapper around (params, opt_state).
 
-    Each ``step`` is one write epoch: mutable borrow -> donated update
-    (the call of the jitted step is a ``train.dispatch`` profiler span) ->
-    color bump on drop.  ``replicate()`` attaches a §4.2.3 backup slot whose
-    write-back is batched per epoch.
+    Each ``step`` is one write epoch: mutable borrow -> update (the call of
+    the jitted step is a ``train.dispatch`` profiler span) -> color bump on
+    drop.  The step donates the state's buffers only while the owner is
+    their sole holder; ``replicate()`` attaches a §4.2.3 backup slot, which
+    keeps each epoch's arrays as they are, so from then on the step writes
+    fresh buffers instead.  While the profiler records, the span carries
+    stat ``donated``: 1 when the step donated the state, else 0.
     """
 
     def __init__(self, cfg: ModelConfig, opt: OptConfig, params,
@@ -77,7 +94,8 @@ class TrainState:
         opt_state = init_opt_state(opt, params)
         self.state = OwnedState("train_state", (params, opt_state))
         fn = make_train_step(cfg, opt, mesh=mesh, microbatches=microbatches)
-        self._step = jax.jit(fn, donate_argnums=(0, 1)) if jit else fn
+        self._jit = jit
+        self._step = _owned_step(fn, self.state) if jit else fn
         self.replicas: list[ReplicaSlot] = []
         self.metrics: dict[str, Any] = {}
 
@@ -93,7 +111,10 @@ class TrainState:
     def step(self, batch):
         with self.state.borrow_mut() as ref:
             params, opt_state = ref.deref_mut()
-            with jax.profiler.TraceAnnotation("train.dispatch"):
+            span = jax.profiler.TraceAnnotation
+            stats = ({"donated": int(self._jit and not self.state.holders)}
+                     if span.is_enabled() else {})
+            with span("train.dispatch", **stats):
                 params, opt_state, metrics = self._step(params, opt_state,
                                                         batch)
             ref.set((params, opt_state))

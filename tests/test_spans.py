@@ -2,10 +2,11 @@
 
 A train step under the profiler writes ``train.dispatch`` (the call of the
 jitted step), ``ownership.epoch`` (the colour bump and the epoch hooks)
-and, inside it, ``replica.flush`` (the backup snapshot) once per step,
-the flush with the bytes it copies as stat ``nbytes``; the lowered step
-carries the
-``attention``, ``mlp``, ``lm_head_loss`` and ``optimizer`` scopes."""
+and, inside it, ``replica.flush`` (the backup snapshot) once per step.
+The dispatch says whether the step donated the state (stat ``donated``),
+the flush the bytes of the snapshot it keeps (``held``) and the bytes it
+copies (``nbytes``, 0); the lowered step carries the ``attention``,
+``mlp``, ``lm_head_loss`` and ``optimizer`` scopes."""
 
 import re
 
@@ -66,18 +67,42 @@ def test_each_span_once_per_step_and_nested(traced):
 
 
 def test_span_stats_match_the_state(traced):
+    """The slot keeps the state without a copy, and the step, whose state
+    the slot holds, does not donate it."""
     _, stats, ts, slot = traced
-    nbytes = sum(x.nbytes for x in jax.tree.leaves(ts.state.read()))
+    held = sum(x.nbytes for x in jax.tree.leaves(ts.state.read()))
     assert [st for _, _, st in stats["replica.flush"]] == [
-        {"nbytes": nbytes}] * STEPS
+        {"nbytes": 0, "held": held}] * STEPS
     assert [st for _, _, st in stats["ownership.epoch"]] == [{}] * STEPS
-    assert [st for _, _, st in stats["train.dispatch"]] == [{}] * STEPS
+    assert [st for _, _, st in stats["train.dispatch"]] == [
+        {"donated": 0}] * STEPS
     assert slot.flushes == STEPS + 1
 
 
+def test_dispatch_donates_without_a_slot(tmp_path):
+    """With no slot the state has no other holder: every traced dispatch
+    donates it, and no flush happens."""
+    cfg, opt, batch = _setup()
+    ts = TrainState(cfg, opt, init_params(cfg, jax.random.PRNGKey(0)))
+    float(ts.step(batch)["loss"])                 # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(STEPS):
+            float(ts.step(batch)["loss"])
+    finally:
+        jax.profiler.stop_trace()
+    [path] = tmp_path.glob("**/*.xplane.pb")
+    stats = span_stats.read(path, SPANS)
+    assert [st for _, _, st in stats["train.dispatch"]] == [
+        {"donated": 1}] * STEPS
+    assert len(stats["ownership.epoch"]) == STEPS
+    assert stats["replica.flush"] == []
+
+
 def test_flush_bytes_counted_anew_while_tracing(tmp_path):
-    """Each traced flush counts the bytes of the tree it copies, also where
-    a leaf changes dtype or shape and the structure stays."""
+    """Each traced flush counts the bytes of the tree it keeps, also where
+    a leaf changes dtype or shape and the structure stays, and copies
+    none."""
     state = OwnedState("t", {"w": jnp.zeros(4)})
     slot = ReplicaSlot(state)
     state.write({"w": jnp.zeros(4)})                   # profiler off
@@ -91,7 +116,8 @@ def test_flush_bytes_counted_anew_while_tracing(tmp_path):
     [path] = tmp_path.glob("**/*.xplane.pb")
     flushes = span_stats.read(path, ["replica.flush"])["replica.flush"]
     assert [st for _, _, st in flushes] == [
-        {"nbytes": 16}, {"nbytes": 8}, {"nbytes": 12}]
+        {"nbytes": 0, "held": 16}, {"nbytes": 0, "held": 8},
+        {"nbytes": 0, "held": 12}]
     assert slot.flushes == 4
 
 

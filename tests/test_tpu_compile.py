@@ -102,19 +102,28 @@ def test_qwen3_decode_step_fits_one_v5e(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
-def test_qwen3_train_step_fits_one_v5e(one_chip):
+@pytest.mark.parametrize("donate", [True, False],
+                         ids=["donating", "slot-held"])
+def test_qwen3_train_step_fits_one_v5e(one_chip, donate):
     """The launcher's train step at full width (batch 8x256, AdamW with
-    f32 moments), with room for the ReplicaSlot backup it keeps: state +
-    backup + temporaries must fit one chip."""
+    f32 moments) fits one chip with the ReplicaSlot backup it keeps.
+    Donating (no slot holds the state): state + a backup's worth +
+    temporaries.  Not donating (the slot's snapshot is the step's own
+    arguments): arguments + outputs + temporaries."""
     cfg, params = _qwen_abstract()
     opt = OptConfig()
     opt_state = jax.eval_shape(functools.partial(init_opt_state, opt), params)
     batch = build_batch_spec(cfg, 8, 256)
-    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1)).lower(
-        _placed(params, one_chip), _placed(opt_state, one_chip),
-        _placed(batch, one_chip)).compile()
+    donated = (0, 1) if donate else ()
+    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=donated
+                       ).lower(_placed(params, one_chip),
+                               _placed(opt_state, one_chip),
+                               _placed(batch, one_chip)).compile()
     mem = compiled.memory_analysis()
-    assert 2 * mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    second = (mem.argument_size_in_bytes if donate
+              else mem.output_size_in_bytes)
+    assert mem.argument_size_in_bytes + second + mem.temp_size_in_bytes \
+        < HBM_BYTES
 
 
 def test_qwen3_sharded_train_step_compiles_for_2x2(topo):

@@ -93,29 +93,79 @@ def test_backup_promotion_restores_epoch():
 
 
 def test_replica_slot_drops_old_backup_and_promotes_newest(monkeypatch):
-    """The flush releases the previous snapshot before it copies the new
-    one (two copies of the state on the device at the flush, not three),
-    and promotion still restores the newest epoch."""
+    """Each flush keeps exactly that epoch's arrays, with no copy, and
+    releases the previous snapshot; promotion restores the newest epoch
+    and colour."""
+    import weakref
     from repro.core.jaxstate import ReplicaSlot
     state = OwnedState("t", {"w": jnp.zeros(4)})
     slot = ReplicaSlot(state)
-    held_during_copy = []
-    real_copy = jnp.copy
-
-    def copy(x):
-        held_during_copy.append(slot.backup)
-        return real_copy(x)
-
-    monkeypatch.setattr(jnp, "copy", copy)
+    assert state.holders == 1
+    copies = []
+    monkeypatch.setattr(jnp, "copy", lambda x: copies.append(x))
+    previous = None
     for v in (1.0, 2.0, 3.0):
-        state.write({"w": jnp.full(4, v)})
-    assert held_during_copy == [None, None, None]
+        tree = {"w": jnp.full(4, v)}
+        state.write(tree)
+        assert slot.backup[0] == state.color
+        assert slot.backup[1] is tree and slot.backup[1]["w"] is tree["w"]
+        if previous is not None:
+            assert previous() is None           # the old snapshot released
+        previous = weakref.ref(tree["w"])
+        del tree
+    assert copies == []
     assert slot.flushes == 3 and slot.backup[0] == 3
     state._tree = {"w": jnp.zeros(4)}       # crash: live buffers lost
     slot.promote()
     assert state.color == 3
     np.testing.assert_array_equal(np.asarray(state.read()["w"]),
                                   np.full(4, 3.0))
+
+
+def test_slot_snapshot_outlives_the_next_step():
+    """With a slot attached the step does not donate: every leaf of the
+    slot's snapshot stays alive and bit-equal to its epoch's state while
+    the next step runs, up to the flush that replaces it."""
+    cfg, params, opt = _setup()
+    ts = TrainState(cfg, opt, params)
+    slot = ts.replicate()
+    data = synthetic_batches(cfg.vocab, 4, 32)
+    seen = {}
+    checked = []
+
+    def before_flush(addr, tree):             # runs ahead of the slot's
+        color, held = slot.backup
+        assert color == addr.color - 1
+        leaves = jax.tree.leaves(held)
+        assert not any(x.is_deleted() for x in leaves)
+        for x, want in zip(leaves, seen[color]):
+            np.testing.assert_array_equal(np.asarray(x), want)
+        assert not any(a is b for a, b in zip(leaves, jax.tree.leaves(tree)))
+        checked.append(color)
+
+    for step in range(4):
+        ts.step(jax.tree.map(jnp.asarray, next(data)))
+        held = jax.tree.leaves(slot.backup[1])
+        live = jax.tree.leaves(ts.state.read())
+        assert all(a is b for a, b in zip(held, live))    # the epoch's own
+        # copies: a zero-copy view would keep the buffer from donation
+        seen[ts.color] = [np.array(x, copy=True) for x in live]
+        if step == 0:
+            ts.state.on_epoch.insert(0, before_flush)
+    assert checked == [1, 2, 3]
+
+
+def test_train_state_without_slot_donates():
+    """With no holder besides the owner the step donates the state: the
+    previous epoch's leaves are deleted once the next step has run."""
+    cfg, params, opt = _setup()
+    ts = TrainState(cfg, opt, params)
+    data = synthetic_batches(cfg.vocab, 4, 32)
+    for _ in range(2):
+        old = jax.tree.leaves(ts.state.read())
+        ts.step(jax.tree.map(jnp.asarray, next(data)))
+        assert all(x.is_deleted() for x in old)
+    assert ts.state.holders == 0
 
 
 def test_dropped_train_state_frees_without_cyclic_gc(tmp_path):
