@@ -2,7 +2,7 @@
 one TPU chip, through the launchers a user calls.
 
     python chip_smoke.py               # one chip: train steps, then requests
-    python chip_smoke.py --four-chips  # (data 2, model 2) train step vs one chip
+    python chip_smoke.py --four-chips  # (data 2, model 2) TrainState vs 1 chip
 
 Everything runs in this one process, which holds the chip(s).  It turns
 on the compile cache the launchers' ``main`` would (``enable_compile_cache``)
@@ -165,17 +165,15 @@ def serve_phase(argv: list[str], clock: CompileClock) -> dict:
 
 def four_chip_phase(cfg, batch: int = 8, seq: int = 256,
                     steps: int = 3) -> dict:
-    """The train step sharded over a (data 2, model 2) mesh with dryrun's
-    shardings, against the one-chip ``TrainState`` on the same batches."""
+    """A ``TrainState`` on a (data 2, model 2) mesh, which places the state
+    and shards its step, against the one-chip ``TrainState`` on the same
+    batches."""
     import jax
     import jax.numpy as jnp
 
-    from repro.dist.sharding import set_mesh
-    from repro.launch.dryrun import sharded_train_step
     from repro.launch.mesh import make_mesh
-    from repro.models import build_batch_spec, init_params
-    from repro.train import (OptConfig, TrainState, init_opt_state,
-                             synthetic_batches)
+    from repro.models import init_params
+    from repro.train import OptConfig, TrainState, synthetic_batches
 
     opt = OptConfig(lr=3e-3, warmup=5, decay_steps=2 * steps)
     data = synthetic_batches(cfg.vocab, batch, seq)
@@ -187,31 +185,18 @@ def four_chip_phase(cfg, batch: int = 8, seq: int = 256,
     del ts
 
     mesh = make_mesh((2, 2), ("data", "model"))
-    set_mesh(mesh)
-    try:
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        step, _, (p_sh, o_sh, b_sh) = sharded_train_step(
-            cfg, opt, mesh, params, build_batch_spec(cfg, batch, seq))
-        params = jax.device_put(params, p_sh)
-        opt_state = jax.device_put(init_opt_state(opt, params), o_sh)
-
-        leaves = jax.tree.leaves(params)
-        held = set().union(*(l.sharding.device_set for l in leaves))
-        check(len(held) == 4, f"parameters on {len(held)} devices, not 4")
-        total = sum(l.nbytes for l in leaves)
-        dev0 = jax.devices()[0]
-        on_dev0 = sum(s.data.nbytes for l in leaves
-                      for s in l.addressable_shards if s.device == dev0)
-        check(on_dev0 < 0.3 * total,
-              f"device 0 holds {on_dev0 / total:.2f} of the parameters")
-
-        sharded = []
-        for b in batches:
-            params, opt_state, m = step(params, opt_state,
-                                        jax.device_put(b, b_sh))
-            sharded.append(float(m["loss"]))
-    finally:
-        set_mesh(None)
+    ts = TrainState(cfg, opt, init_params(cfg, jax.random.PRNGKey(0)),
+                    mesh=mesh)
+    leaves = jax.tree.leaves(ts.params())
+    held = set().union(*(l.sharding.device_set for l in leaves))
+    check(len(held) == 4, f"parameters on {len(held)} devices, not 4")
+    total = sum(l.nbytes for l in leaves)
+    dev0 = jax.devices()[0]
+    on_dev0 = sum(s.data.nbytes for l in leaves
+                  for s in l.addressable_shards if s.device == dev0)
+    check(on_dev0 < 0.3 * total,
+          f"device 0 holds {on_dev0 / total:.2f} of the parameters")
+    sharded = [float(ts.step(b)["loss"]) for b in batches]
     for a, b in zip(one, sharded):
         check(math.isfinite(b) and abs(a - b) <= LOSS_RTOL * abs(a),
               f"sharded losses {sharded} vs one-chip {one}")

@@ -12,7 +12,7 @@ import importlib
 ARCHS = [
     "qwen3_moe_235b", "arctic_480b", "rwkv6_3b", "pixtral_12b", "gemma_7b",
     "qwen3_0_6b", "granite_34b", "starcoder2_3b", "musicgen_medium",
-    "recurrentgemma_9b",
+    "recurrentgemma_9b", "qwen3_1_7b",
 ]
 
 ALIASES = {
@@ -26,6 +26,7 @@ ALIASES = {
     "starcoder2-3b": "starcoder2_3b",
     "musicgen-medium": "musicgen_medium",
     "recurrentgemma-9b": "recurrentgemma_9b",
+    "qwen3-1.7b": "qwen3_1_7b",
 }
 
 # (name, seq_len, global_batch, mode)

@@ -32,7 +32,7 @@ from __future__ import annotations
 import re
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.tree_util import tree_flatten_with_path, tree_unflatten
 
 # ---------------------------------------------------------------------------
@@ -231,6 +231,21 @@ def opt_state_specs(mesh, opt_state, params):
 
     return {k: (tied(v) if isinstance(v, dict) else P())
             for k, v in opt_state.items()}
+
+
+def train_shardings(mesh, params, opt_state=None, batch=None):
+    """The ``NamedSharding`` trees of a train step's arguments on ``mesh``:
+    parameters by ``param_specs``, optimizer state tied to them by
+    ``opt_state_specs``, inputs by ``batch_specs``.  Trees may be abstract
+    or concrete; a part not given comes back as None.  Returns
+    ``(params, opt_state, batch)``."""
+    def named(specs):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, P))
+    return (named(param_specs(mesh, params)),
+            None if opt_state is None
+            else named(opt_state_specs(mesh, opt_state, params)),
+            None if batch is None else named(batch_specs(mesh, batch)))
 
 
 # ---------------------------------------------------------------------------

@@ -87,6 +87,14 @@ def collective_bytes(hlo_text: str, while_mult: int = 1) -> dict:
     return {k: int(v) for k, v in out.items()}
 
 
+def layer_trips(cfg, microbatches: int = 1) -> int:
+    """How often one step runs its layer scan's body: the ``while_mult``
+    of ``collective_bytes``."""
+    period = cfg.attn_every or 1
+    n_steps = (cfg.n_layers // period) if cfg.scan_layers else 1
+    return max(1, n_steps * max(1, microbatches))
+
+
 # §Perf-confirmed per-cell optimization policy (EXPERIMENTS §4): the
 # paper-faithful rules stay the default; --optimized applies these.
 SMALL_DENSE = {"qwen3_0_6b", "starcoder2_3b", "gemma_7b", "musicgen_medium",
@@ -129,10 +137,9 @@ def _cost_of(lowered_or_compiled) -> dict:
 
 def sharded_train_step(cfg, opt, mesh, params_abs, batch_abs, *,
                        microbatches: int = 1):
-    """The train step jitted with the mesh's shardings: parameters by
-    ``param_specs``, optimizer state tied to them by ``opt_state_specs``,
-    inputs by ``batch_specs``; (params, opt_state) are donated.  The caller
-    installs ``mesh`` with ``set_mesh`` before the first call traces it.
+    """The train step as a ``TrainState`` on ``mesh`` jits it (donating
+    variant), with the inputs' shardings as well: shardings from
+    ``train_shardings``, the jit from ``jit_train_step``.
 
     Returns (jitted step, abstract optimizer state, (param, opt_state,
     batch) NamedSharding trees).
@@ -140,24 +147,16 @@ def sharded_train_step(cfg, opt, mesh, params_abs, batch_abs, *,
     import functools
 
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec
 
-    from repro.dist.sharding import batch_specs, opt_state_specs, param_specs
+    from repro.dist.sharding import train_shardings
     from repro.train.optimizer import init_opt_state
-    from repro.train.train_step import make_train_step
+    from repro.train.train_step import jit_train_step, make_train_step
 
-    ns = lambda spec: NamedSharding(mesh, spec)
     opt_abs = jax.eval_shape(functools.partial(init_opt_state, opt),
                              params_abs)
-    p_shard = jax.tree.map(ns, param_specs(mesh, params_abs))
-    o_shard = jax.tree.map(ns, opt_state_specs(mesh, opt_abs, params_abs),
-                           is_leaf=lambda x: isinstance(x, PartitionSpec))
-    b_shard = jax.tree.map(ns, batch_specs(mesh, batch_abs))
+    shardings = train_shardings(mesh, params_abs, opt_abs, batch_abs)
     fn = make_train_step(cfg, opt, mesh=mesh, microbatches=microbatches)
-    jitted = jax.jit(fn, in_shardings=(p_shard, o_shard, b_shard),
-                     out_shardings=(p_shard, o_shard, None),
-                     donate_argnums=(0, 1))
-    return jitted, opt_abs, (p_shard, o_shard, b_shard)
+    return jit_train_step(fn, shardings), opt_abs, shardings
 
 
 def lower_cell(arch: str, shape_name: str, mesh, *, opt_name: str | None = None,
@@ -304,8 +303,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *, opt_name: str | None = None,
             "calibrated": False}
 
     hlo = compiled.as_text()
-    rec["collectives"] = collective_bytes(hlo, while_mult=max(
-        1, n_steps * max(1, microbatches)))
+    rec["collectives"] = collective_bytes(
+        hlo, while_mult=layer_trips(cfg, microbatches))
     rec["hlo_lines"] = hlo.count("\n")
     set_mesh(None)
     if verbose:

@@ -18,8 +18,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, devices=None):
     """Arbitrary mesh (tests, smoke dry-runs on few host devices, the
-    chips of one host)."""
+    chips of one host), over ``devices`` where given."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(AxisType.Auto,) * len(axes))
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)

@@ -2,18 +2,22 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
         [--full] [--steps 50] [--batch 8] [--seq 256] [--ckpt-dir DIR] \
+        [--mesh DxM]    (train sharded over a (data D, model M) mesh)
         [--fail-at N]   (inject a failure: restore from the epoch backup)
 
 Runs the real loop: synthetic data -> ownership-wrapped train state ->
 jitted step (color bump per epoch; the backup slot keeps each epoch's
 arrays, so the step does not donate them) -> epoch-batched
 checkpointing -> optional failure injection + recovery.  The default is
-the reduced smoke config; ``--full`` trains the published widths.
+the reduced smoke config; ``--full`` trains the published widths.  With
+``--mesh``, the weights are made on the mesh, FSDP over ``data`` and tensor
+parallel over ``model`` (``dist.sharding``), and the state stays there.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -32,6 +36,8 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--fail-at", type=int, default=0)
+    ap.add_argument("--mesh", default=None,
+                    help="DxM: shard over a (data D, model M) mesh")
     ap.add_argument("--lr", type=float, default=3e-3)
     return ap.parse_args(argv)
 
@@ -42,17 +48,29 @@ def run(args: argparse.Namespace) -> dict:
     the compile."""
     from repro import configs
     from repro.checkpoint import CheckpointManager
+    from repro.dist.sharding import train_shardings
+    from repro.launch.mesh import make_mesh
     from repro.models import init_params
     from repro.train import OptConfig, TrainState, synthetic_batches
 
     cfg = configs.get(args.arch) if args.full else configs.smoke(args.arch)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(0)
+    mesh = None
+    if args.mesh:
+        mesh = make_mesh(tuple(int(n) for n in args.mesh.split("x")),
+                         ("data", "model"))
+        init = functools.partial(init_params, cfg)
+        shardings, _, _ = train_shardings(mesh, jax.eval_shape(init, key))
+        params = jax.jit(init, out_shardings=shardings)(key)
+    else:
+        params = init_params(cfg, key)
     n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
     print(f"arch={cfg.name} params={n_params/1e6:.2f}M "
-          f"batch={args.batch}x{args.seq}")
+          f"batch={args.batch}x{args.seq} mesh={args.mesh or 'none'}")
 
     opt = OptConfig(lr=args.lr, warmup=5, decay_steps=args.steps * 2)
-    ts = TrainState(cfg, opt, params, microbatches=args.microbatches)
+    ts = TrainState(cfg, opt, params, mesh=mesh,
+                    microbatches=args.microbatches)
     ts.replicate()                                # §4.2.3 backup slot
     mgr = None
     if args.ckpt_dir:

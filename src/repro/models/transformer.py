@@ -211,7 +211,7 @@ def _block(cfg: ModelConfig, p, x, positions, cache, length, layer_idx,
         y, new_c = RGLRU.rglru_block(cfg, p["rec"], h,
                                      cache if cache is not None else None)
     x = x + y
-    x = shard_act(x)
+    x = shard_act(x, mesh)
 
     h2 = L.rms_norm(x, p["norm2"], cfg.norm_eps)
     aux = 0.0
@@ -227,7 +227,7 @@ def _block(cfg: ModelConfig, p, x, positions, cache, length, layer_idx,
         else:
             y2 = L.mlp_block(cfg, p["mlp"], h2)
     x = x + y2
-    x = shard_act(x)
+    x = shard_act(x, mesh)
     return x, (new_c if cache is not None else None), aux
 
 
@@ -258,7 +258,7 @@ def _forward_body(cfg: ModelConfig, params, batch, mesh=None):
             [batch["prefix_embeds"].astype(x.dtype), x], axis=1)
     T = x.shape[1]
     positions = jnp.arange(T, dtype=jnp.int32)
-    x = shard_act(x)
+    x = shard_act(x, mesh)
     period = cfg.attn_every or 1
     aux_total = 0.0
 

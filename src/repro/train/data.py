@@ -36,11 +36,17 @@ def synthetic_batches(vocab: int, global_batch: int, seq_len: int,
 
 
 def shard_batch(mesh, batch):
-    """Place a host batch onto the mesh with the canonical input sharding."""
+    """Place a host batch onto the mesh with the canonical input sharding;
+    a leaf already on the mesh stays as it is."""
     if mesh is None:
         return jax.tree.map(jax.numpy.asarray, batch)
+
+    def put(x, spec):
+        sharding = getattr(x, "sharding", None)
+        if isinstance(sharding, NamedSharding) and sharding.mesh == mesh:
+            return x
+        return jax.device_put(x, NamedSharding(mesh, spec))
+
     abstract = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
-    specs = batch_specs(mesh, abstract)
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), batch, specs)
+    return jax.tree.map(put, batch, batch_specs(mesh, abstract))

@@ -3,7 +3,8 @@
 A train step under the profiler writes ``train.dispatch`` (the call of the
 jitted step), ``ownership.epoch`` (the colour bump and the epoch hooks)
 and, inside it, ``replica.flush`` (the backup snapshot) once per step.
-The dispatch says whether the step donated the state (stat ``donated``),
+The dispatch says whether the step donated the state (stat ``donated``)
+and over how many devices it lies (``chips``: 1 here, with no mesh),
 the flush the bytes of the snapshot it keeps (``held``) and the bytes it
 copies (``nbytes``, 0); the lowered step carries the ``attention``,
 ``mlp``, ``lm_head_loss`` and ``optimizer`` scopes."""
@@ -75,7 +76,7 @@ def test_span_stats_match_the_state(traced):
         {"nbytes": 0, "held": held}] * STEPS
     assert [st for _, _, st in stats["ownership.epoch"]] == [{}] * STEPS
     assert [st for _, _, st in stats["train.dispatch"]] == [
-        {"donated": 0}] * STEPS
+        {"donated": 0, "chips": 1}] * STEPS
     assert slot.flushes == STEPS + 1
 
 
@@ -94,7 +95,7 @@ def test_dispatch_donates_without_a_slot(tmp_path):
     [path] = tmp_path.glob("**/*.xplane.pb")
     stats = span_stats.read(path, SPANS)
     assert [st for _, _, st in stats["train.dispatch"]] == [
-        {"donated": 1}] * STEPS
+        {"donated": 1, "chips": 1}] * STEPS
     assert len(stats["ownership.epoch"]) == STEPS
     assert stats["replica.flush"] == []
 

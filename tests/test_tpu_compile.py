@@ -147,3 +147,35 @@ def test_qwen3_sharded_train_step_compiles_for_2x2(topo):
     # every chip holds about a quarter of the state, not all of it
     assert mem.argument_size_in_bytes < 0.3 * state_bytes
     assert "all-reduce" in compiled.as_text()
+
+
+def test_qwen3_1_7b_train_state_step_fits_2x2(topo):
+    """qwen3-1.7b's ``TrainState`` step on a (data 2, model 2) mesh at
+    4 x 2,048 tokens, its shardings from ``train_shardings`` and its jit
+    from ``jit_train_step``, as ``TrainState`` makes them: the
+    slot-held variant (arguments + outputs + temporaries, the larger of
+    the two) fits one chip, each chip holds about a quarter of the state,
+    and the step has collectives."""
+    from repro.dist.sharding import train_shardings
+    from repro.launch.dryrun import collective_bytes, layer_trips
+    from repro.train.train_step import jit_train_step
+    cfg = configs.get("qwen3-1.7b")
+    params = jax.eval_shape(functools.partial(init_params, cfg),
+                            jax.random.PRNGKey(0))
+    opt = OptConfig()
+    opt_state = jax.eval_shape(functools.partial(init_opt_state, opt), params)
+    batch = build_batch_spec(cfg, 4, 2048)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    shardings = train_shardings(mesh, params, opt_state, batch)
+    compiled = jit_train_step(make_train_step(cfg, opt, mesh=mesh), shardings,
+                              donate=False).lower(params, opt_state,
+                                                  batch).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(l.size * l.dtype.itemsize
+                      for l in jax.tree.leaves((params, opt_state)))
+    assert state_bytes > HBM_BYTES                # no one chip holds it
+    assert mem.argument_size_in_bytes < 0.3 * state_bytes
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < HBM_BYTES
+    wire = collective_bytes(compiled.as_text(), layer_trips(cfg))
+    assert wire["all-gather"] > 0 and wire["all-reduce"] > 0
