@@ -137,12 +137,14 @@ def _cost_of(lowered_or_compiled) -> dict:
 
 def sharded_train_step(cfg, opt, mesh, params_abs, batch_abs, *,
                        microbatches: int = 1):
-    """The train step as a ``TrainState`` on ``mesh`` jits it (donating
-    variant), with the inputs' shardings as well: shardings from
-    ``train_shardings``, the jit from ``jit_train_step``.
+    """The train step as a ``TrainState`` on ``mesh`` compiles it
+    (donating variant), with the inputs' shardings as well: shardings
+    from ``train_shardings``, the jit from ``jit_train_step``, the remat
+    policy from ``compile_fitting``.
 
-    Returns (jitted step, abstract optimizer state, (param, opt_state,
-    batch) NamedSharding trees).
+    Returns (``Fitted``: policy, saved bytes, jitted step and its compiled
+    program; abstract optimizer state; (param, opt_state, batch)
+    NamedSharding trees).
     """
     import functools
 
@@ -150,13 +152,17 @@ def sharded_train_step(cfg, opt, mesh, params_abs, batch_abs, *,
 
     from repro.dist.sharding import train_shardings
     from repro.train.optimizer import init_opt_state
-    from repro.train.train_step import jit_train_step, make_train_step
+    from repro.train.train_step import (compile_fitting, jit_train_step,
+                                        make_train_step)
 
     opt_abs = jax.eval_shape(functools.partial(init_opt_state, opt),
                              params_abs)
     shardings = train_shardings(mesh, params_abs, opt_abs, batch_abs)
-    fn = make_train_step(cfg, opt, mesh=mesh, microbatches=microbatches)
-    return jit_train_step(fn, shardings), opt_abs, shardings
+    fitted = compile_fitting(
+        lambda policy: jit_train_step(
+            make_train_step(cfg, opt, mesh, microbatches, policy), shardings),
+        (params_abs, opt_abs, batch_abs), cfg, mesh, microbatches)
+    return fitted, opt_abs, shardings
 
 
 def lower_cell(arch: str, shape_name: str, mesh, *, opt_name: str | None = None,
@@ -198,15 +204,21 @@ def lower_cell(arch: str, shape_name: str, mesh, *, opt_name: str | None = None,
     opt = OptConfig(name=opt_name or
                     ("adafactor" if arch == "arctic_480b" else "adamw"))
 
+    remat_policy = None
+
     def build(cfg2):
-        """Lower one variant; returns (lowered, abstract param tree)."""
+        """Lower one variant; returns (lowered, abstract param tree).  A
+        train step compiles here already, to choose its remat policy."""
+        nonlocal remat_policy
         params_abs = jax.eval_shape(functools.partial(init_params, cfg2),
                                     jax.random.PRNGKey(0))
         if mode == "train":
-            jitted, opt_abs, _ = sharded_train_step(
+            fitted, opt_abs, _ = sharded_train_step(
                 cfg2, opt, mesh, params_abs, batch_abs,
                 microbatches=microbatches)
-            return jitted.lower(params_abs, opt_abs, batch_abs), params_abs
+            remat_policy = fitted.policy
+            return fitted.jitted.lower(params_abs, opt_abs,
+                                       batch_abs), params_abs
         p_shard = jax.tree.map(ns, param_specs(mesh, params_abs))
         b_shard = jax.tree.map(ns, batch_specs(mesh, batch_abs))
         if mode == "prefill":
@@ -273,7 +285,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *, opt_name: str | None = None,
             if mode == "train":
                 o_abs = jax.eval_shape(
                     functools.partial(init_opt_state, opt), p_abs)
-                fn = make_train_step(cfg2, opt, mesh=None)
+                fn = make_train_step(cfg2, opt, mesh=None,
+                                     remat_policy=remat_policy)
                 return jax.jit(fn).lower(p_abs, o_abs, batch_abs)
             if mode == "prefill":
                 from repro.serve.serve_step import make_prefill

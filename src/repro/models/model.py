@@ -15,13 +15,16 @@ forward = T.forward
 decode_step = T.decode_step
 
 
-def loss_fn(cfg: ModelConfig, params, batch, mesh=None):
+def loss_fn(cfg: ModelConfig, params, batch, mesh=None,
+            remat_policy=T.RECOMPUTE):
     """Causal-LM cross entropy (+ MoE load-balance aux).
 
     With ``cfg.chunked_ce = n`` the head matmul + CE run per sequence-chunk
     inside a scan, so the (B,T,V) logits (bf16 *and* the f32 cast) never
-    materialize — the §Perf memory-term optimization."""
-    (x, aux), head = T.forward_hidden(cfg, params, batch, mesh=mesh)
+    materialize — the §Perf memory-term optimization.  ``remat_policy``
+    is the layer scan's checkpoint policy under ``cfg.remat``."""
+    (x, aux), head = T.forward_hidden(cfg, params, batch, mesh=mesh,
+                                      remat_policy=remat_policy)
     return _lm_head_loss(cfg, x, head, batch) + 0.01 * aux
 
 

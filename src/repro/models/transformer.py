@@ -1,5 +1,7 @@
 """Decoder assembly for every family: scan-over-layers (compile-time at
-512 devices), per-layer remat, KV / ring / recurrent-state caches.
+512 devices), per-layer remat (each layer recomputed, or its projection
+outputs kept: ``SAVE_PROJECTIONS``, ``saved_bytes``), KV / ring /
+recurrent-state caches.
 
 Layer recipes
   dense/vlm/audio : x += attn(norm(x));  x += mlp(norm(x))
@@ -18,17 +20,19 @@ Caches
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from .config import ModelConfig
 from . import layers as L
 from . import moe as MOE
 from . import rwkv as RWKV
 from . import rglru as RGLRU
-from repro.dist.sharding import shard_act, current_mesh
+from repro.dist.sharding import activation_spec, current_mesh, shard_act
 
 
 # ---------------------------------------------------------------------------
@@ -232,6 +236,47 @@ def _block(cfg: ModelConfig, p, x, positions, cache, length, layer_idx,
 
 
 # ---------------------------------------------------------------------------
+#  remat policy
+# ---------------------------------------------------------------------------
+SAVE_PROJECTIONS = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+RECOMPUTE = jax.checkpoint_policies.nothing_saveable
+
+
+def saved_bytes(cfg: ModelConfig, x_shape, mesh=None) -> int:
+    """The bytes a chip keeps for the backward when the layer scan over a
+    residual stream of ``x_shape`` (B, T, ...) runs under
+    ``SAVE_PROJECTIONS``, or 0 where that policy is not offered: remat
+    off, or a block this count does not describe (recurrent, expert).
+
+    Saving keeps the projection outputs of every layer that the backward
+    reads (q, k, v, o, gate, up: the dots with no batch dims; the down
+    projection's output no backward rule needs), so the backward scan no
+    longer runs each layer's forward again; the attention scores, which
+    have batch dims, are still recomputed.  They are counted as the
+    layers × the (B, T, width) of each in the residual stream's layout
+    (``activation_spec``: batch over the data axes, sequence over
+    ``model``), which is how the compiled 2x2 program keeps them.  Whether
+    they fit a chip is the train step's to decide
+    (``train_step.compile_fitting``)."""
+    n_layers = _layer_plan(cfg)[0]
+    if not cfg.remat or not n_layers or cfg.family == "rwkv" \
+            or cfg.attn_every or cfg.n_experts:
+        return 0
+    mesh = mesh if mesh is not None else current_mesh()
+    B, T = x_shape[:2]
+    widths = (cfg.n_heads * cfg.hd, cfg.n_kv_heads * cfg.hd,
+              cfg.n_kv_heads * cfg.hd, cfg.d_model, cfg.d_ff, cfg.d_ff)
+    per_layer = 0
+    for w in widths:
+        shape = (B, T, w)
+        if mesh is not None:
+            shape = NamedSharding(mesh, activation_spec(mesh, shape)
+                                  ).shard_shape(shape)
+        per_layer += math.prod(shape)
+    return n_layers * per_layer * jnp.dtype(cfg.dtype).itemsize
+
+
+# ---------------------------------------------------------------------------
 #  forward / decode
 # ---------------------------------------------------------------------------
 def forward(cfg: ModelConfig, params, batch, mesh=None):
@@ -243,14 +288,18 @@ def forward(cfg: ModelConfig, params, batch, mesh=None):
     return logits, aux_total
 
 
-def forward_hidden(cfg: ModelConfig, params, batch, mesh=None):
+def forward_hidden(cfg: ModelConfig, params, batch, mesh=None,
+                   remat_policy=RECOMPUTE):
     """Forward up to the final norm (no logits) — used by the chunked-CE
-    loss so the (B,T,V) f32 logits never materialize."""
+    loss so the (B,T,V) f32 logits never materialize.  ``remat_policy``
+    is the layer scan's checkpoint policy under ``cfg.remat``."""
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return _forward_body(cfg, params, batch, mesh=mesh), head
+    return _forward_body(cfg, params, batch, mesh=mesh,
+                         remat_policy=remat_policy), head
 
 
-def _forward_body(cfg: ModelConfig, params, batch, mesh=None):
+def _forward_body(cfg: ModelConfig, params, batch, mesh=None,
+                  remat_policy=RECOMPUTE):
     tokens = batch["tokens"]
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.prefix_len and "prefix_embeds" in batch:
@@ -275,8 +324,7 @@ def _forward_body(cfg: ModelConfig, params, batch, mesh=None):
         return x, aux
 
     if cfg.remat:
-        block_fn = jax.checkpoint(block_fn,
-                                  policy=jax.checkpoint_policies.nothing_saveable)
+        block_fn = jax.checkpoint(block_fn, policy=remat_policy)
     if cfg.scan_layers:
         x, auxs = jax.lax.scan(lambda c, p: block_fn(c, p), x,
                                params["layers"])
@@ -289,8 +337,7 @@ def _forward_body(cfg: ModelConfig, params, batch, mesh=None):
                                mesh=mesh)
             return x, aux
         if cfg.remat:
-            one = jax.checkpoint(
-                one, policy=jax.checkpoint_policies.nothing_saveable)
+            one = jax.checkpoint(one, policy=remat_policy)
         for p_layer in params["layers"]:
             x, aux = one(x, p_layer)
             aux_total = aux_total + aux

@@ -3,8 +3,10 @@
 A train step under the profiler writes ``train.dispatch`` (the call of the
 jitted step), ``ownership.epoch`` (the colour bump and the epoch hooks)
 and, inside it, ``replica.flush`` (the backup snapshot) once per step.
-The dispatch says whether the step donated the state (stat ``donated``)
-and over how many devices it lies (``chips``: 1 here, with no mesh),
+The dispatch says whether the step donated the state (stat ``donated``),
+over how many devices it lies (``chips``: 1 here, with no mesh) and
+what its layer scan keeps for the backward (``remat_saved_bytes``, the
+count of ``step_saved_bytes`` where the step saves),
 the flush the bytes of the snapshot it keeps (``held``) and the bytes it
 copies (``nbytes``, 0); the lowered step carries the ``attention``,
 ``mlp``, ``lm_head_loss`` and ``optimizer`` scopes."""
@@ -23,10 +25,18 @@ from repro.core.jaxstate import OwnedState, ReplicaSlot
 from repro.models import init_params
 from repro.train import OptConfig, TrainState
 from repro.train.optimizer import init_opt_state
-from repro.train.train_step import make_train_step
+from repro.train.train_step import make_train_step, step_saved_bytes
 
 SPANS = ("train.dispatch", "ownership.epoch", "replica.flush")
 STEPS = 3
+
+
+def _saved(ts, batch) -> int:
+    """What the step keeps for its backward when it saves, as it does at
+    this size."""
+    saved = step_saved_bytes(ts.cfg, batch)
+    assert saved > 0
+    return saved
 
 
 def _setup():
@@ -76,7 +86,8 @@ def test_span_stats_match_the_state(traced):
         {"nbytes": 0, "held": held}] * STEPS
     assert [st for _, _, st in stats["ownership.epoch"]] == [{}] * STEPS
     assert [st for _, _, st in stats["train.dispatch"]] == [
-        {"donated": 0, "chips": 1}] * STEPS
+        {"donated": 0, "chips": 1,
+         "remat_saved_bytes": _saved(ts, _setup()[2])}] * STEPS
     assert slot.flushes == STEPS + 1
 
 
@@ -95,7 +106,8 @@ def test_dispatch_donates_without_a_slot(tmp_path):
     [path] = tmp_path.glob("**/*.xplane.pb")
     stats = span_stats.read(path, SPANS)
     assert [st for _, _, st in stats["train.dispatch"]] == [
-        {"donated": 1, "chips": 1}] * STEPS
+        {"donated": 1, "chips": 1,
+         "remat_saved_bytes": _saved(ts, batch)}] * STEPS
     assert len(stats["ownership.epoch"]) == STEPS
     assert stats["replica.flush"] == []
 

@@ -9,6 +9,8 @@ compiles (an entry compiled for a described chip cannot be read back).
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +20,9 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro import configs
 from repro.models import build_batch_spec, init_cache, init_params
+from repro.models.transformer import RECOMPUTE, SAVE_PROJECTIONS
 from repro.train import OptConfig, init_opt_state, make_train_step
+from repro.train.train_step import compile_fitting, jit_train_step
 
 HBM_BYTES = 16 * 2**30                # one v5e chip
 
@@ -49,6 +53,51 @@ def _placed(tree, sharding):
     return jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
         tree)
+
+
+def _stacked_bytes(text: str, lead: str) -> int:
+    """Bytes of the bf16 buffers whose shape starts with ``lead`` in the
+    widest ``while`` of a compiled program: the layer scan's stacked
+    residuals, which the forward hands the backward."""
+    return max(sum(math.prod(map(int, dims.split(","))) * 2
+                   for dims in re.findall(r"bf16\[([\d,]+)\]", shape)
+                   if dims.startswith(lead))
+               for shape in re.findall(r"^\s*%\S+ = (.*) while\(", text,
+                                       re.M))
+
+
+def _peak(compiled) -> int:
+    """A chip's bytes for the program: arguments + the outputs it writes
+    beside its donated arguments + temporaries."""
+    mem = compiled.memory_analysis()
+    return mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+
+
+def _fitted(cfg, opt, args, donate, mesh=None, shardings=None):
+    """``compile_fitting`` as ``TrainState`` calls it, and the jitted steps
+    it tried, in order (their compiles are cached)."""
+    tried = []
+
+    def jit_for(policy):
+        tried.append(jit_train_step(
+            make_train_step(cfg, opt, mesh, remat_policy=policy), shardings,
+            donate))
+        return tried[-1]
+
+    return compile_fitting(jit_for, args, cfg, mesh), tried
+
+
+def _saves_its_projections(cfg, fitted, chip_rows) -> None:
+    """The layer scan saves its projection outputs, and the compiled
+    program's forward scan carries exactly the chooser's bytes of them
+    beside the stacked layer inputs, whose first dims after the layers
+    are ``chip_rows`` (a chip's share of batch and sequence)."""
+    assert fitted.policy is SAVE_PROJECTIONS and fitted.saved > 0
+    layer_inputs = cfg.n_layers * math.prod(chip_rows) * cfg.d_model * 2
+    lead = ",".join(map(str, (cfg.n_layers,) + chip_rows)) + ","
+    assert _stacked_bytes(fitted.compiled.as_text(), lead) \
+        == fitted.saved + layer_inputs
 
 
 def _qwen_abstract():
@@ -106,20 +155,20 @@ def test_qwen3_decode_step_fits_one_v5e(one_chip):
                          ids=["donating", "slot-held"])
 def test_qwen3_train_step_fits_one_v5e(one_chip, donate):
     """The launcher's train step at full width (batch 8x256, AdamW with
-    f32 moments) fits one chip with the ReplicaSlot backup it keeps.
-    Donating (no slot holds the state): state + a backup's worth +
-    temporaries.  Not donating (the slot's snapshot is the step's own
-    arguments): arguments + outputs + temporaries."""
+    f32 moments) fits one chip with the ReplicaSlot backup it keeps, its
+    layer scan saving the projection outputs.  Donating (no slot holds
+    the state): state + a backup's worth + temporaries.  Not donating
+    (the slot's snapshot is the step's own arguments, as in the
+    short-rows cell): arguments + outputs + temporaries."""
     cfg, params = _qwen_abstract()
     opt = OptConfig()
     opt_state = jax.eval_shape(functools.partial(init_opt_state, opt), params)
     batch = build_batch_spec(cfg, 8, 256)
-    donated = (0, 1) if donate else ()
-    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=donated
-                       ).lower(_placed(params, one_chip),
-                               _placed(opt_state, one_chip),
-                               _placed(batch, one_chip)).compile()
-    mem = compiled.memory_analysis()
+    fitted, _ = _fitted(cfg, opt, (_placed(params, one_chip),
+                                   _placed(opt_state, one_chip),
+                                   _placed(batch, one_chip)), donate)
+    _saves_its_projections(cfg, fitted, (8, 256))
+    mem = fitted.compiled.memory_analysis()
     second = (mem.argument_size_in_bytes if donate
               else mem.output_size_in_bytes)
     assert mem.argument_size_in_bytes + second + mem.temp_size_in_bytes \
@@ -135,12 +184,12 @@ def test_qwen3_sharded_train_step_compiles_for_2x2(topo):
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
     set_mesh(mesh)
     try:
-        jitted, opt_state, _ = sharded_train_step(
+        fitted, opt_state, _ = sharded_train_step(
             cfg, OptConfig(), mesh, params, build_batch_spec(cfg, 8, 256))
-        compiled = jitted.lower(params, opt_state,
-                                build_batch_spec(cfg, 8, 256)).compile()
     finally:
         set_mesh(None)
+    assert fitted.policy is SAVE_PROJECTIONS
+    compiled = fitted.compiled
     mem = compiled.memory_analysis()
     state_bytes = sum(l.size * l.dtype.itemsize
                       for l in jax.tree.leaves((params, opt_state)))
@@ -149,33 +198,42 @@ def test_qwen3_sharded_train_step_compiles_for_2x2(topo):
     assert "all-reduce" in compiled.as_text()
 
 
-def test_qwen3_1_7b_train_state_step_fits_2x2(topo):
+@pytest.mark.parametrize("donate,seq", [(True, 2048), (False, 2048),
+                                        (False, 4096)],
+                         ids=["donating", "slot-held", "slot-held-4096"])
+def test_qwen3_1_7b_train_state_step_fits_2x2(topo, donate, seq):
     """qwen3-1.7b's ``TrainState`` step on a (data 2, model 2) mesh at
-    4 x 2,048 tokens, its shardings from ``train_shardings`` and its jit
-    from ``jit_train_step``, as ``TrainState`` makes them: the
-    slot-held variant (arguments + outputs + temporaries, the larger of
-    the two) fits one chip, each chip holds about a quarter of the state,
-    and the step has collectives."""
+    4 x ``seq`` tokens, its shardings from ``train_shardings``, its jit
+    from ``jit_train_step`` and its remat policy from ``compile_fitting``,
+    as ``TrainState`` makes them: it fits one chip, each chip holds about
+    a quarter of the state, and the step has collectives.  At 4 x 2,048
+    both variants save their layers' projection outputs (donating, as in
+    the four-chip cell; slot-held, as the launcher runs it).  At 4 x
+    4,096 slot-held, saving would need more than a chip has, so the
+    layers are recomputed, as before saving existed."""
     from repro.dist.sharding import train_shardings
     from repro.launch.dryrun import collective_bytes, layer_trips
-    from repro.train.train_step import jit_train_step
     cfg = configs.get("qwen3-1.7b")
     params = jax.eval_shape(functools.partial(init_params, cfg),
                             jax.random.PRNGKey(0))
     opt = OptConfig()
     opt_state = jax.eval_shape(functools.partial(init_opt_state, opt), params)
-    batch = build_batch_spec(cfg, 4, 2048)
+    batch = build_batch_spec(cfg, 4, seq)
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
     shardings = train_shardings(mesh, params, opt_state, batch)
-    compiled = jit_train_step(make_train_step(cfg, opt, mesh=mesh), shardings,
-                              donate=False).lower(params, opt_state,
-                                                  batch).compile()
+    args = (params, opt_state, batch)
+    fitted, tried = _fitted(cfg, opt, args, donate, mesh, shardings)
+    if seq == 2048:
+        _saves_its_projections(cfg, fitted, (2, 1024))
+    else:
+        assert fitted.policy is RECOMPUTE and fitted.saved == 0
+        assert _peak(tried[0].lower(*args).compile()) > HBM_BYTES
+    compiled = fitted.compiled
     mem = compiled.memory_analysis()
     state_bytes = sum(l.size * l.dtype.itemsize
                       for l in jax.tree.leaves((params, opt_state)))
     assert state_bytes > HBM_BYTES                # no one chip holds it
     assert mem.argument_size_in_bytes < 0.3 * state_bytes
-    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
-        + mem.temp_size_in_bytes < HBM_BYTES
+    assert _peak(compiled) < HBM_BYTES
     wire = collective_bytes(compiled.as_text(), layer_trips(cfg))
     assert wire["all-gather"] > 0 and wire["all-reduce"] > 0

@@ -3,9 +3,9 @@ child process (the device count is fixed before JAX starts): it places
 the state over the four, keeps it there through both variants of its
 step, trains as the one-device ``TrainState`` and the plain float32
 reference do on the same seeded weights, keeps the ownership epoch
-(colour, backup slot, promotion), and puts ``chips`` and
-``collective_bytes`` on ``train.dispatch`` only while the profiler
-records."""
+(colour, backup slot, promotion), and puts ``chips``,
+``remat_saved_bytes`` and ``collective_bytes`` on ``train.dispatch`` only
+while the profiler records."""
 
 import json
 import math
@@ -34,6 +34,7 @@ CHILD = textwrap.dedent('''
     from repro.launch.mesh import make_mesh
     from repro.models.config import ModelConfig
     from repro.train import OptConfig, TrainState, shard_batch
+    from repro.train.train_step import step_saved_bytes
 
     CFG = {"name": "tiny", "rope_theta": 1e6, "rms_norm_eps": 1e-6,
            "tie_word_embeddings": True, "torch_dtype": "float32",
@@ -146,6 +147,7 @@ CHILD = textwrap.dedent('''
         text = text.compile().as_text()
         out["wire_bytes"] = sum(collective_bytes(
             text, while_mult=layer_trips(mcfg)).values())
+        out["remat_saved"] = step_saved_bytes(mcfg, batches[0], mesh)
     print(json.dumps(out))
 ''')
 
@@ -199,5 +201,7 @@ def test_slot_stops_donation_and_restores_on_the_mesh(run):
 def test_dispatch_stats_only_while_recording(run):
     assert run["stats_off"] == [{}]
     assert run["wire_bytes"] > 0
+    assert run["remat_saved"] > 0
     assert run["stats_on"] == [{"donated": 1, "chips": 4,
+                                "remat_saved_bytes": run["remat_saved"],
                                 "collective_bytes": run["wire_bytes"]}] * 2
