@@ -1,8 +1,8 @@
 """Assigned architecture configs (``--arch <id>``).
 
-Every entry is the exact published configuration; ``SHAPES`` are the
-assigned input-shape cells.  ``get(name)`` returns the ModelConfig;
-``SMOKE(name)`` its reduced same-family variant for CPU tests.
+Every entry is the exact published configuration.  ``get(name)`` returns
+the ModelConfig; ``smoke(name)`` its reduced same-family variant for CPU
+tests.
 """
 
 from __future__ import annotations
@@ -29,17 +29,6 @@ ALIASES = {
     "qwen3-1.7b": "qwen3_1_7b",
 }
 
-# (name, seq_len, global_batch, mode)
-SHAPES = {
-    "train_4k": (4096, 256, "train"),
-    "prefill_32k": (32768, 32, "prefill"),
-    "decode_32k": (32768, 128, "decode"),
-    "long_500k": (524288, 1, "decode"),
-}
-
-# long_500k needs sub-quadratic attention: only these archs run it
-LONG_OK = {"rwkv6_3b", "recurrentgemma_9b"}
-
 
 def get(name: str):
     key = ALIASES.get(name, name.replace("-", "_").replace(".", "_"))
@@ -50,13 +39,3 @@ def get(name: str):
 def smoke(name: str):
     return get(name).smoke()
 
-
-def cells():
-    """All runnable (arch, shape) dry-run cells."""
-    out = []
-    for a in ARCHS:
-        for s in SHAPES:
-            if s == "long_500k" and a not in LONG_OK:
-                continue
-            out.append((a, s))
-    return out

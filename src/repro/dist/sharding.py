@@ -16,15 +16,11 @@ Layout contract (see ``models/layers.py``):
   * scan-stacked trees carry a leading layer-group dim — rules match the
     *trailing* dims, so stacked and unrolled trees share one table.
 
-Rule flags (process-wide, like the mesh registry):
-  * ``dp_only``       — pure ZeRO-3: every leaf FSDP-sharded along its first
-                        dividing dim over *all* mesh axes; batch over all axes.
-  * ``serve_weights`` — TP-only weights (no FSDP data axes): serving has no
-                        optimizer state to amortize, and re-gathering weights
-                        per token dominates decode collectives.
-  * ``ulysses``       — inputs arrive sequence-sharded over ``model``; the
-                        attention all-to-all (``ulysses_heads``) re-shards
-                        seq<->heads around the score computation.
+No state: every function takes the mesh it lays out for; ``shard_act``
+alone also takes None (one device), and then does nothing.  Batch and
+activations go over the data axes (``pod``, ``data``), as does the FSDP
+half of every weight rule; ``model`` carries tensor parallelism and the
+residual stream's sequence.
 """
 
 from __future__ import annotations
@@ -34,37 +30,6 @@ import re
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.tree_util import tree_flatten_with_path, tree_unflatten
-
-# ---------------------------------------------------------------------------
-#  mesh + rule-flag registry
-# ---------------------------------------------------------------------------
-_MESH = None
-_FLAGS = {"ulysses": False, "dp_only": False, "serve_weights": False}
-
-
-def set_mesh(mesh):
-    """Install (or clear, with ``None``) the process-wide mesh."""
-    global _MESH
-    _MESH = mesh
-    return mesh
-
-
-def current_mesh():
-    return _MESH
-
-
-def set_rule_flags(**flags):
-    """Update rule flags; unknown keys are rejected."""
-    for k, v in flags.items():
-        if k not in _FLAGS:
-            raise ValueError(f"unknown rule flag {k!r}")
-        _FLAGS[k] = bool(v)
-    return dict(_FLAGS)
-
-
-def rule_flags() -> dict:
-    return dict(_FLAGS)
-
 
 # ---------------------------------------------------------------------------
 #  divisor fitting
@@ -107,16 +72,9 @@ def _fit(mesh, spec, shape) -> P:
     return P(*out)
 
 
-def _pod_data_axes(mesh) -> tuple:
-    return tuple(a for a in ("pod", "data") if a in dict(mesh.shape))
-
-
 def _dp_axes(mesh) -> tuple:
-    """Axes that carry the batch: (pod, data) normally, every axis under
-    dp_only, nothing for weight specs under serve_weights (see callers)."""
-    if _FLAGS["dp_only"]:
-        return tuple(dict(mesh.shape))
-    return _pod_data_axes(mesh)
+    """Axes that carry the batch, and the FSDP half of the weights."""
+    return tuple(a for a in ("pod", "data") if a in dict(mesh.shape))
 
 
 # ---------------------------------------------------------------------------
@@ -167,34 +125,8 @@ def _path_str(path) -> str:
 
 def _resolve(token, mesh):
     if token == "dp":
-        if _FLAGS["serve_weights"]:
-            return None
-        return _pod_data_axes(mesh) or None
-    if token == "model":
-        return "model"
+        return _dp_axes(mesh) or None
     return token
-
-
-def _zero3_spec(mesh, shape) -> P:
-    """dp_only: FSDP-shard the dim covering the most mesh axes (longest
-    dividing prefix of the full axis tuple); earliest dim wins ties."""
-    axes = tuple(dict(mesh.shape))
-    best = None                                  # (coverage, dim, kept)
-    for i, dim in enumerate(shape):
-        kept = axes
-        while kept:
-            n = _axis_size(mesh, kept)
-            if n is not None and dim % n == 0:
-                break
-            kept = kept[:-1]
-        if kept:
-            cov = _axis_size(mesh, kept)
-            if best is None or cov > best[0]:
-                best = (cov, i, kept)
-    entries = [None] * len(shape)
-    if best is not None:
-        entries[best[1]] = best[2]
-    return P(*entries)
 
 
 def param_specs(mesh, params):
@@ -203,9 +135,6 @@ def param_specs(mesh, params):
     specs = []
     for path, leaf in flat:
         shape = leaf.shape
-        if _FLAGS["dp_only"]:
-            specs.append(_zero3_spec(mesh, shape))
-            continue
         name = _path_str(path)
         for rx, tokens in _COMPILED_RULES:
             if rx.search(name):
@@ -252,60 +181,37 @@ def train_shardings(mesh, params, opt_state=None, batch=None):
 #  data / activation / cache specs
 # ---------------------------------------------------------------------------
 def batch_specs(mesh, batch):
-    """Inputs: batch dim over the dp axes; under the ulysses flag the
-    sequence dim is additionally sharded over ``model`` (the attention
-    all-to-all re-shards it to heads)."""
+    """Inputs: batch dim over the dp axes, the rest replicated."""
     dp = _dp_axes(mesh)
-    seq = "model" if (_FLAGS["ulysses"] and not _FLAGS["dp_only"]) else None
 
     def one(leaf):
         nd = len(leaf.shape)
         if nd == 0:
             return P()
-        entries = (dp or None,) + (seq,) * (1 if nd > 1 else 0) \
-            + (None,) * max(0, nd - 2)
-        return _fit(mesh, P(*entries), leaf.shape)
+        return _fit(mesh, P(dp or None, *(None,) * (nd - 1)), leaf.shape)
 
     return jax.tree.map(one, batch)
 
 
 def activation_spec(mesh, shape) -> P:
     """(B, T, D) residual-stream layout: batch over dp, sequence over
-    ``model`` (Megatron-style sequence parallelism).  dp_only drops the
-    sequence sharding (pure data parallel)."""
+    ``model`` (Megatron-style sequence parallelism)."""
     dp = _dp_axes(mesh)
     if len(shape) == 0:
         return P()
-    if _FLAGS["dp_only"]:
-        entries = (dp or None,) + (None,) * (len(shape) - 1)
-    else:
-        entries = (dp or None,) \
-            + (("model",) if len(shape) > 1 else ()) \
-            + (None,) * max(0, len(shape) - 2)
+    entries = (dp or None,) \
+        + (("model",) if len(shape) > 1 else ()) \
+        + (None,) * max(0, len(shape) - 2)
     return _fit(mesh, P(*entries), shape)
 
 
-def shard_act(x, mesh=None):
-    """Constrain an activation to the canonical layout (no-op off-mesh)."""
-    mesh = mesh if mesh is not None else _MESH
+def shard_act(x, mesh):
+    """Constrain an activation to the canonical layout on ``mesh``; with
+    no mesh, ``x`` as it is."""
     if mesh is None:
         return x
-    spec = activation_spec(mesh, x.shape)
     return jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(mesh, spec))
-
-
-def ulysses_heads(x, mesh=None):
-    """Ulysses sequence parallelism: re-shard (B, T, H, hd) from
-    sequence-over-model to heads-over-model.  XLA lowers the constraint
-    flip to a single all-to-all; identity when no mesh is installed."""
-    mesh = mesh if mesh is not None else _MESH
-    if mesh is None or "model" not in dict(mesh.shape):
-        return x
-    dp = _pod_data_axes(mesh)
-    spec = _fit(mesh, P(dp or None, None, "model", None), x.shape)
-    return jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(mesh, spec))
+        x, NamedSharding(mesh, activation_spec(mesh, x.shape)))
 
 
 def cache_specs(mesh, cache):
@@ -313,16 +219,13 @@ def cache_specs(mesh, cache):
     k/v (B, S, Hkv, hd), rwkv S (B, H, M, M)) shard dim 1 over ``model``
     so decode attention can keep every cache shard local."""
     dp = _dp_axes(mesh)
-    # dp_only already spreads the batch over `model`; reusing it on the
-    # sequence dim would duplicate the axis in one spec
-    seq = None if _FLAGS["dp_only"] else "model"
 
     def one(leaf):
         nd = len(leaf.shape)
         if nd == 0:
             return P()
         if nd >= 4:
-            entries = (dp or None, seq) + (None,) * (nd - 2)
+            entries = (dp or None, "model") + (None,) * (nd - 2)
         elif nd >= 2:
             entries = (dp or None,) + (None,) * (nd - 1)
         else:

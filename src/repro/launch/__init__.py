@@ -1,1 +1,2 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Entry points: the train and serve launchers, elastic reshard, meshes,
+the compile cache."""

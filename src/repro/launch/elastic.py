@@ -30,7 +30,7 @@ def run(arch: str = "qwen3-0.6b", from_mesh=(2, 4), to_mesh=(4, 2),
         verbose: bool = True) -> bool:
     from repro import configs
     from repro.checkpoint import restore, save
-    from repro.dist.sharding import param_specs, set_mesh
+    from repro.dist.sharding import param_specs
     from repro.launch.mesh import make_mesh
     from repro.models import init_params
 
@@ -38,7 +38,6 @@ def run(arch: str = "qwen3-0.6b", from_mesh=(2, 4), to_mesh=(4, 2),
     params = init_params(cfg, jax.random.PRNGKey(0))
 
     mesh_a = make_mesh(from_mesh, ("data", "model"))
-    set_mesh(mesh_a)
     specs_a = param_specs(mesh_a, params)
     sharded_a = jax.tree.map(
         lambda x, s: jax.device_put(x, jax.sharding.NamedSharding(mesh_a, s)),
@@ -48,7 +47,6 @@ def run(arch: str = "qwen3-0.6b", from_mesh=(2, 4), to_mesh=(4, 2),
         save(f"{d}/ck", sharded_a, color=3)
 
         mesh_b = make_mesh(to_mesh, ("data", "model"))
-        set_mesh(mesh_b)
         like = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
         specs_b = param_specs(mesh_b, like)
@@ -59,7 +57,6 @@ def run(arch: str = "qwen3-0.6b", from_mesh=(2, 4), to_mesh=(4, 2),
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):
         ok &= bool(np.allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32), atol=1e-6))
-    set_mesh(None)
     if verbose:
         print(f"elastic reshard {from_mesh} -> {to_mesh}: "
               f"{'OK' if ok else 'MISMATCH'} (epoch color {manifest['color']})")

@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 A function, not a module-level constant: importing this module must never
 touch jax device state (a caller may still set XLA_FLAGS before jax
@@ -11,16 +11,9 @@ import jax
 from jax.sharding import AxisType
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; 2 pods = 512 chips when ``multi_pod``."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def make_mesh(shape, axes, devices=None):
-    """Arbitrary mesh (tests, smoke dry-runs on few host devices, the
-    chips of one host), over ``devices`` where given."""
+    """Arbitrary mesh (tests on host devices, the chips of one host),
+    over ``devices`` where given."""
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes),
                          devices=devices)

@@ -45,20 +45,9 @@ class ModelConfig:
     remat: bool = True
     scan_layers: bool = True
     attn_chunk: int = 1024           # kv-block size for the chunked XLA path
-    attn_impl: str = "xla"           # xla | pallas (nothing reads it yet)
     max_target_len: int = 8192       # serving cache default
-    unroll_chunks: bool = False      # rwkv: python loop (flops calibration)
-    unroll_experts: bool = False     # moe: python loop (flops calibration)
     # ---- beyond-paper perf knobs (EXPERIMENTS §Perf) ----
-    ulysses: bool = False            # all-to-all seq<->head resharding
     chunked_ce: int = 0              # CE loss in vocab-chunks (0 = off)
-    decode_shard_s: bool = False     # shard_map decode attn (S stays local)
-    moe_a2a: bool = False            # all-to-all token dispatch for EP
-    serve_weights_tp_only: bool = False  # serving: no FSDP (no opt state to
-                                         # amortize; re-gathering per token
-                                         # dominates decode collectives)
-    dp_only: bool = False            # pure ZeRO-3: batch over every mesh
-                                     # axis, weights FSDP-sharded, no TP/SP
 
     @property
     def hd(self) -> int:

@@ -135,9 +135,6 @@ def attn_block(cfg: ModelConfig, p, x, positions, *, cache=None,
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
-    if cfg.ulysses and cache is None:
-        from repro.dist.sharding import ulysses_heads
-        q, k, v = ulysses_heads(q), ulysses_heads(k), ulysses_heads(v)
 
     if cache is None:
         out = attention(q, k, v, positions, positions, window=window,

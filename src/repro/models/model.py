@@ -63,7 +63,8 @@ def _lm_head_loss(cfg: ModelConfig, x, head, batch):
 
 def build_batch_spec(cfg: ModelConfig, global_batch: int, seq_len: int,
                      mode: str = "train"):
-    """ShapeDtypeStructs for every model input (dry-run stand-ins)."""
+    """ShapeDtypeStructs for every model input (stand-ins for compiling
+    a step without data)."""
     if mode in ("train", "prefill"):
         spec = {
             "tokens": jax.ShapeDtypeStruct((global_batch, seq_len), jnp.int32),

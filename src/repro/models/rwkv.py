@@ -142,16 +142,7 @@ def time_mix(cfg: ModelConfig, p, x, state):
         return S2, o
 
     split = lambda a: a.reshape(B, H, nc, CHUNK, M).transpose(2, 0, 1, 3, 4)
-    if cfg.unroll_chunks:            # flops-calibration path (no while loop)
-        xs = (split(r), split(k), split(v), split(logw))
-        os = []
-        for c in range(nc):
-            S, o_c = step(S, jax.tree.map(lambda a: a[c], xs))
-            os.append(o_c)
-        o = jnp.stack(os, axis=0)
-    else:
-        S, o = jax.lax.scan(step, S,
-                            (split(r), split(k), split(v), split(logw)))
+    S, o = jax.lax.scan(step, S, (split(r), split(k), split(v), split(logw)))
     o = o.transpose(1, 2, 0, 3, 4).reshape(B, H, Tpad, M)[:, :, :T]
     o = o.transpose(0, 2, 1, 3).reshape(B, T, D).astype(x.dtype)
     o = rms_norm(o, p["ln_x"], cfg.norm_eps) * g

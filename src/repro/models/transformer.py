@@ -1,7 +1,7 @@
-"""Decoder assembly for every family: scan-over-layers (compile-time at
-512 devices), per-layer remat (each layer recomputed, or its projection
-outputs kept: ``SAVE_PROJECTIONS``, ``saved_bytes``), KV / ring /
-recurrent-state caches.
+"""Decoder assembly for every family: scan-over-layers (one compiled
+layer body, whatever the depth), per-layer remat (each layer recomputed,
+or its projection outputs kept: ``SAVE_PROJECTIONS``, ``saved_bytes``),
+KV / ring / recurrent-state caches.
 
 Layer recipes
   dense/vlm/audio : x += attn(norm(x));  x += mlp(norm(x))
@@ -32,7 +32,7 @@ from . import layers as L
 from . import moe as MOE
 from . import rwkv as RWKV
 from . import rglru as RGLRU
-from repro.dist.sharding import activation_spec, current_mesh, shard_act
+from repro.dist.sharding import activation_spec, shard_act
 
 
 # ---------------------------------------------------------------------------
@@ -199,12 +199,6 @@ def _block(cfg: ModelConfig, p, x, positions, cache, length, layer_idx,
             if cache is not None and cfg.window:
                 y, new_c = _attn_with_ring(cfg, p["attn"], h, positions,
                                            cache, length)
-            elif (cache is not None and cfg.decode_shard_s
-                  and (mesh or current_mesh()) is not None):
-                from .decode_sharded import attn_decode_sharded
-                y, new_c = attn_decode_sharded(cfg, mesh or current_mesh(),
-                                               p["attn"], h, positions, cache,
-                                               length)
             else:
                 c = None if cache is None else {**cache, "length": length}
                 y, new_c = L.attn_block(cfg, p["attn"], h, positions,
@@ -221,7 +215,6 @@ def _block(cfg: ModelConfig, p, x, positions, cache, length, layer_idx,
     aux = 0.0
     with jax.named_scope("mlp"):
         if cfg.n_experts:
-            mesh = mesh or current_mesh()
             if mesh is not None:
                 y2, aux = MOE.moe_shardmap(cfg, mesh, p["moe"], h2)
             else:
@@ -262,7 +255,6 @@ def saved_bytes(cfg: ModelConfig, x_shape, mesh=None) -> int:
     if not cfg.remat or not n_layers or cfg.family == "rwkv" \
             or cfg.attn_every or cfg.n_experts:
         return 0
-    mesh = mesh if mesh is not None else current_mesh()
     B, T = x_shape[:2]
     widths = (cfg.n_heads * cfg.hd, cfg.n_kv_heads * cfg.hd,
               cfg.n_kv_heads * cfg.hd, cfg.d_model, cfg.d_ff, cfg.d_ff)

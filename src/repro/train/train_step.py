@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.jaxstate import OwnedState, ReplicaSlot
+from repro.dist.collectives import collective_bytes, layer_trips
 from repro.dist.sharding import train_shardings
 from repro.models import loss_fn
 from repro.models.config import ModelConfig
@@ -191,7 +192,7 @@ class _OwnedStep:
     def variant(self, params, opt_state, batch) -> _Variant:
         """The variant the next call runs, compiled at its first call;
         on a mesh with its collectives' per-chip wire bytes by
-        ``launch.dryrun.collective_bytes``."""
+        ``dist.collectives.collective_bytes``."""
         donate = not self.state.holders
         leaves, tree = jax.tree.flatten(batch)
         key = (donate, tree, tuple((x.shape, x.dtype) for x in leaves))
@@ -203,7 +204,6 @@ class _OwnedStep:
                 self.microbatches)
             wire = None
             if self.mesh is not None:
-                from repro.launch.dryrun import collective_bytes, layer_trips
                 wire = sum(collective_bytes(
                     fitted.compiled.as_text(),
                     while_mult=layer_trips(self.cfg, self.microbatches)

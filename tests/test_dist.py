@@ -1,6 +1,6 @@
 """Direct unit tests for the repro.dist subsystem: sharding rules under
-odd mesh sizes, int8 compression error bounds, and the pipeline schedule
-(bubble accounting + microbatch semantics)."""
+odd mesh sizes and the four-chip cell's layout, int8 compression error
+bounds, and the HLO collective-bytes parser."""
 
 import types
 
@@ -12,23 +12,18 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import configs
-from repro.dist import compression, pipeline, sharding
-from repro.dist.sharding import (_fit, activation_spec, batch_specs,
-                                 cache_specs, opt_state_specs, param_specs,
-                                 set_mesh, set_rule_flags, ulysses_heads)
-from repro.launch.mesh import make_mesh
-from repro.models import init_cache, init_params
+from repro.dist import compression
+from repro.dist.collectives import collective_bytes
+from repro.dist.sharding import (_fit, _path_str, activation_spec,
+                                 batch_specs, cache_specs, opt_state_specs,
+                                 param_specs)
+from repro.models import build_batch_spec, init_cache, init_params
 
 KEY = jax.random.PRNGKey(0)
 
 
 def fake_mesh(**shape):
     return types.SimpleNamespace(shape=shape)
-
-
-def teardown_function(_fn=None):
-    set_mesh(None)
-    set_rule_flags(ulysses=False, dp_only=False, serve_weights=False)
 
 
 def _check_divisible(mesh, abstract, specs):
@@ -104,63 +99,60 @@ def test_cache_specs_shard_sequence_over_model():
     assert k_spec[1] == "model" and k_spec[0] in (("data",), "data")
 
 
-def test_cache_specs_dp_only_never_duplicates_axes():
-    """Under dp_only the batch spreads over every axis — the sequence dim
-    must not reuse `model` (NamedSharding rejects duplicate axes)."""
-    mesh = make_mesh((1, 1), ("data", "model"))
-    set_rule_flags(dp_only=True)
-    spec = jax.tree.leaves(
-        cache_specs(mesh, {"k": jax.ShapeDtypeStruct((4, 128, 4, 32),
-                                                     jnp.bfloat16)}),
-        is_leaf=lambda x: isinstance(x, P))[0]
-    jax.sharding.NamedSharding(mesh, spec)        # raises on duplicates
-    set_rule_flags(dp_only=False)
-
-
-def test_serve_weights_flag_drops_fsdp_axes():
-    m = fake_mesh(data=8, model=4)
-    cfg = configs.smoke("gemma_7b")
-    abstract = jax.eval_shape(lambda k: init_params(cfg, k), KEY)
-    set_rule_flags(serve_weights=True)
-    specs = param_specs(m, abstract)
-    for spec in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P)):
-        for axes in tuple(spec):
-            axes = axes if isinstance(axes, tuple) else (axes,)
-            assert "data" not in axes and "pod" not in axes
-    set_rule_flags(serve_weights=False)
-
-
-def test_ulysses_flag_shards_sequence_in_batch_specs():
-    m = fake_mesh(data=2, model=4)
-    b = {"tokens": jax.ShapeDtypeStruct((8, 64), jnp.int32)}
-    base = jax.tree.leaves(batch_specs(m, b),
-                           is_leaf=lambda x: isinstance(x, P))[0]
-    assert base[1] is None
-    set_rule_flags(ulysses=True)
-    uly = jax.tree.leaves(batch_specs(m, b),
-                          is_leaf=lambda x: isinstance(x, P))[0]
-    assert uly[1] == "model"
-    set_rule_flags(ulysses=False)
-
-
 def test_activation_spec_odd_dims_replicate():
     m = fake_mesh(data=3, model=5)
     assert activation_spec(m, (9, 25, 7)) == P(("data",), "model", None)
     assert activation_spec(m, (8, 24, 7)) == P(None, None, None)
-    set_rule_flags(dp_only=True)
-    assert activation_spec(m, (15, 25, 7)) == P(("data", "model"), None, None)
-    set_rule_flags(dp_only=False)
 
 
-def test_ulysses_heads_identity_off_mesh():
-    x = jnp.ones((2, 8, 4, 16))
-    np.testing.assert_array_equal(np.asarray(ulysses_heads(x)),
-                                  np.asarray(x))
+# The four-chip cell's layout: qwen3-1.7b on (data 2, model 2), FSDP over
+# `data`, tensor parallelism over `model`.
+QWEN3_1_7B_2X2 = {
+    "embed": P("model", "data"),
+    "final_norm": P(None),
+    "layers/attn/k_norm": P(None, None),
+    "layers/attn/q_norm": P(None, None),
+    "layers/attn/wk": P(None, "data", "model", None),
+    "layers/attn/wo": P(None, "model", None, "data"),
+    "layers/attn/wq": P(None, "data", "model", None),
+    "layers/attn/wv": P(None, "data", "model", None),
+    "layers/mlp/w_down": P(None, "model", "data"),
+    "layers/mlp/w_gate": P(None, "data", "model"),
+    "layers/mlp/w_up": P(None, "data", "model"),
+    "layers/norm1": P(None, None),
+    "layers/norm2": P(None, None),
+}
 
 
-def test_set_rule_flags_rejects_unknown():
-    with pytest.raises(ValueError):
-        set_rule_flags(zeRO=True)
+@pytest.fixture(scope="module")
+def qwen3_1_7b_2x2():
+    """(mesh, cfg, {leaf path: (shape, spec)}) of qwen3-1.7b's parameters
+    on a (data 2, model 2) mesh."""
+    m = fake_mesh(data=2, model=2)
+    cfg = configs.get("qwen3-1.7b")
+    abstract = jax.eval_shape(lambda k: init_params(cfg, k), KEY)
+    leaves = jax.tree_util.tree_flatten_with_path(abstract)[0]
+    specs = jax.tree.leaves(param_specs(m, abstract),
+                            is_leaf=lambda x: isinstance(x, P))
+    return m, cfg, {_path_str(path): (leaf.shape, spec)
+                    for (path, leaf), spec in zip(leaves, specs)}
+
+
+@pytest.mark.parametrize("leaf", sorted(QWEN3_1_7B_2X2) + ["batch",
+                                                          "activation"])
+def test_qwen3_1_7b_layout_on_2x2(qwen3_1_7b_2x2, leaf):
+    """Each parameter leaf, the 4 x 2,048 batch and the residual stream
+    of the four-chip cell keep the layout they are trained in."""
+    m, cfg, layout = qwen3_1_7b_2x2
+    if leaf == "batch":
+        specs = batch_specs(m, build_batch_spec(cfg, 4, 2048))
+        assert specs == {"tokens": P("data", None), "labels": P("data", None)}
+    elif leaf == "activation":
+        assert activation_spec(m, (4, 2048, cfg.d_model)) \
+            == P("data", "model", None)
+    else:
+        assert sorted(layout) == sorted(QWEN3_1_7B_2X2)
+        assert layout[leaf][1] == QWEN3_1_7B_2X2[leaf]
 
 
 # -------------------------------------------------------------- compression
@@ -210,38 +202,52 @@ def test_tree_quantize_roundtrip_and_wire_bytes():
     assert compression.wire_bytes(packed) < compression.wire_bytes(tree) / 3
 
 
-# ----------------------------------------------------------------- pipeline
-def test_bubble_accounting():
-    assert pipeline.schedule_steps(1, 4) == 4
-    assert pipeline.schedule_steps(4, 8) == 11
-    assert pipeline.bubble_stage_steps(1, 4) == 0
-    assert pipeline.bubble_stage_steps(4, 8) == 4 * 3
-    assert pipeline.bubble_fraction(1, 16) == 0.0
-    np.testing.assert_allclose(pipeline.bubble_fraction(4, 8), 3 / 11)
-    # more microbatches shrink the bubble monotonically
-    fracs = [pipeline.bubble_fraction(4, m) for m in (1, 2, 4, 16, 64)]
-    assert all(a > b for a, b in zip(fracs, fracs[1:]))
+# -------------------------------------------------------------- collectives
+# One HLO line per kind, with the per-device wire bytes the parser must
+# give at while_mult 10: result bytes x the ring factor of its group size
+# G (x10 inside a while body); a -done line carries no new bytes.
+_COLLECTIVE_LINES = {
+    "all-gather-in-while": (
+        "all-gather",
+        '%ag = bf16[16,256,4096]{2,1,0} all-gather(%x), channel_id=1, '
+        'replica_groups=[2,4]<=[8], dimensions={1}, '
+        'metadata={op_name="jit(f)/while/body/ag"}',
+        16 * 256 * 4096 * 2 * (3 / 4) * 10),
+    "all-reduce": (
+        "all-reduce",
+        '%ar = f32[1024]{0} all-reduce(%y), channel_id=2, '
+        'replica_groups=[4,2]<=[8], to_apply=%add, '
+        'metadata={op_name="jit(f)/ar"}',
+        1024 * 4 * 2 * (1 / 2)),
+    "reduce-scatter": (
+        "reduce-scatter",
+        '%rs = f32[64,64]{1,0} reduce-scatter(%z), channel_id=3, '
+        'replica_groups=[2,4]<=[8], dimensions={0}, '
+        'metadata={op_name="jit(f)/rs"}',
+        64 * 64 * 4 * 3),
+    "all-to-all": (
+        "all-to-all",
+        '%a2a = bf16[8,128]{1,0} all-to-all(%w), channel_id=4, '
+        'replica_groups={{0,1,2,3},{4,5,6,7}}, dimensions={0}, '
+        'metadata={op_name="jit(f)/a2a"}',
+        8 * 128 * 2 * (3 / 4)),
+    "collective-permute": (
+        "collective-permute",
+        '%cp = f32[256]{0} collective-permute(%v), channel_id=5, '
+        'source_target_pairs={{0,1},{1,0}}, '
+        'metadata={op_name="jit(f)/cp"}',
+        256 * 4),
+    "done-counts-zero": (
+        "all-gather",
+        '%agd = bf16[16,256]{1,0} all-gather-done(%ags), '
+        'metadata={op_name="jit(f)/agd"}',
+        0),
+}
 
 
-def test_pipeline_apply_validates_microbatching():
-    mesh = make_mesh((1,), ("pod",))
-    w = jnp.ones((1, 4, 4))
-    x = jnp.ones((6, 4))
-    with pytest.raises(ValueError):
-        pipeline.pipeline_apply(lambda p, xb: xb @ p, mesh, w, x,
-                                n_microbatches=4)   # 6 % 4 != 0
-    with pytest.raises(ValueError):
-        pipeline.pipeline_apply(lambda p, xb: xb @ p, mesh,
-                                jnp.ones((3, 4, 4)), x)  # no axis of size 3
-
-
-def test_pipeline_single_stage_microbatch_counts_agree():
-    mesh = make_mesh((1,), ("pod",))
-    rng = np.random.default_rng(3)
-    w = jnp.asarray(rng.standard_normal((1, 4, 4)) * 0.5, jnp.float32)
-    x = jnp.asarray(rng.standard_normal((8, 4)), jnp.float32)
-    outs = [pipeline.pipeline_apply(lambda p, xb: jnp.tanh(xb @ p), mesh, w,
-                                    x, n_microbatches=m) for m in (1, 2, 8)]
-    for o in outs[1:]:
-        np.testing.assert_allclose(np.asarray(o), np.asarray(outs[0]),
-                                   rtol=1e-6)
+@pytest.mark.parametrize("case", list(_COLLECTIVE_LINES))
+def test_collective_bytes_parser(case):
+    kind, line, expected = _COLLECTIVE_LINES[case]
+    out = collective_bytes("\n  " + line + "\n", while_mult=10)
+    assert out[kind] == pytest.approx(expected, rel=1e-6, abs=0)
+    assert all(v == 0 for k, v in out.items() if k != kind)

@@ -1,7 +1,9 @@
-"""Semantic equivalence of the §Perf variants vs the baseline paths.
+"""The mesh path against the off-mesh path, and the chunked loss against
+the full one.
 
-These run on a 1x1 (data, model) mesh so the shard_map/a2a code paths
-execute for real (single shard), and must reproduce baseline numerics.
+The mesh cases run on a 1x1 (data, model) mesh, so the sharding
+constraints and the MoE shard_map execute for real (one shard), and must
+reproduce what the same call computes with no mesh.
 """
 
 import dataclasses
@@ -12,21 +14,16 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.dist.sharding import set_mesh, set_rule_flags
 from repro.launch.mesh import make_mesh
 from repro.models import (decode_step, forward, init_cache, init_params,
                           loss_fn)
+from repro.models.moe import moe_block, moe_params, moe_shardmap
 
 KEY = jax.random.PRNGKey(0)
 
 
 def mesh11():
     return make_mesh((1, 1), ("data", "model"))
-
-
-def teardown_function(_fn=None):
-    set_mesh(None)
-    set_rule_flags(ulysses=False, dp_only=False, serve_weights=False)
 
 
 def test_chunked_ce_matches_full():
@@ -40,71 +37,44 @@ def test_chunked_ce_matches_full():
     np.testing.assert_allclose(chunked, full, rtol=1e-5)
 
 
-def test_moe_a2a_matches_gather_dispatch():
-    """a2a dispatch == gather dispatch when capacity admits every token."""
-    mesh = mesh11()
-    set_mesh(mesh)
-    cfg = dataclasses.replace(configs.smoke("qwen3_moe_235b"),
-                              dtype="float32", capacity_factor=8.0)
-    from repro.models.moe import moe_params, moe_shardmap
-    p = moe_params(cfg, KEY, jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model)) * 0.3
-    y_gather, aux_g = moe_shardmap(cfg, mesh, p, x)
-    y_a2a, aux_a = moe_shardmap(dataclasses.replace(cfg, moe_a2a=True),
-                                mesh, p, x)
-    np.testing.assert_allclose(np.asarray(y_a2a), np.asarray(y_gather),
-                               rtol=2e-3, atol=2e-3)
-    np.testing.assert_allclose(float(aux_a), float(aux_g), rtol=1e-4)
-    set_mesh(None)
-
-
-def test_decode_shard_s_matches_baseline():
-    mesh = mesh11()
-    set_mesh(mesh)
-    cfg = dataclasses.replace(configs.smoke("granite_34b"), dtype="float32")
-    params = init_params(cfg, KEY)
-    rng = np.random.default_rng(2)
-    toks = jnp.asarray(rng.integers(0, cfg.vocab, (2, 6)), jnp.int32)
-
-    def run(c):
-        cache = init_cache(c, 2, 64)
-        outs = []
-        for t in range(6):
-            lg, cache = decode_step(c, params, cache, toks[:, t:t + 1],
-                                    mesh=mesh)
-            outs.append(lg)
-        return jnp.concatenate(outs, axis=1)
-
-    base = run(cfg)
-    sharded = run(dataclasses.replace(cfg, decode_shard_s=True))
-    np.testing.assert_allclose(np.asarray(sharded), np.asarray(base),
-                               rtol=2e-3, atol=2e-3)
-    set_mesh(None)
-
-
-def test_dp_only_rules_shard_first_dim():
-    import types
-    from jax.sharding import PartitionSpec as P
-    from repro.dist.sharding import param_specs
-    set_rule_flags(dp_only=True)
-    m = types.SimpleNamespace(shape={"data": 16, "model": 16})
-    cfg = configs.get("gemma_7b")
-    abstract = jax.eval_shape(lambda k: init_params(cfg, k), KEY)
-    specs = param_specs(m, abstract)
-    wg = specs["layers"]["mlp"]["w_gate"]       # (L, D, F)
-    assert "model" in (wg[1] if isinstance(wg[1], tuple) else (wg[1],))
-    set_rule_flags(dp_only=False)
-
-
-def test_ulysses_forward_matches_baseline_numerics():
-    mesh = mesh11()
-    set_mesh(mesh)
+def _forward(mesh):
     cfg = dataclasses.replace(configs.smoke("gemma_7b"), dtype="float32")
     params = init_params(cfg, KEY)
-    b = {"tokens": jnp.zeros((2, 32), jnp.int32)}
-    base, _ = forward(cfg, params, b, mesh=mesh)
-    uly, _ = forward(dataclasses.replace(cfg, ulysses=True), params, b,
-                     mesh=mesh)
-    np.testing.assert_allclose(np.asarray(uly), np.asarray(base),
-                               rtol=1e-4, atol=1e-4)
-    set_mesh(None)
+    logits, _ = forward(cfg, params, {"tokens": jnp.zeros((2, 32), jnp.int32)},
+                        mesh=mesh)
+    return [(logits, 1e-4, 1e-4)]
+
+
+def _decode(mesh):
+    """Six decode steps of a granite block against a 64-token cache."""
+    cfg = dataclasses.replace(configs.smoke("granite_34b"), dtype="float32")
+    params = init_params(cfg, KEY)
+    toks = jnp.asarray(np.random.default_rng(2).integers(0, cfg.vocab, (2, 6)),
+                       jnp.int32)
+    cache = init_cache(cfg, 2, 64)
+    outs = []
+    for t in range(6):
+        lg, cache = decode_step(cfg, params, cache, toks[:, t:t + 1],
+                                mesh=mesh)
+        outs.append(lg)
+    return [(jnp.concatenate(outs, axis=1), 2e-3, 2e-3)]
+
+
+def _moe(mesh):
+    """The expert block, with capacity for every token, on the mesh
+    through its shard_map and off it directly."""
+    cfg = dataclasses.replace(configs.smoke("qwen3_moe_235b"),
+                              dtype="float32", capacity_factor=8.0)
+    p = moe_params(cfg, KEY, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model)) * 0.3
+    y, aux = moe_block(cfg, p, x) if mesh is None \
+        else moe_shardmap(cfg, mesh, p, x)
+    return [(y, 2e-3, 2e-3), (aux, 1e-4, 0)]
+
+
+@pytest.mark.parametrize("run", [_forward, _decode, _moe],
+                         ids=["forward", "decode", "moe"])
+def test_mesh_path_matches_off_mesh(run):
+    for (a, rtol, atol), (b, _, _) in zip(run(mesh11()), run(None)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=rtol, atol=atol)

@@ -19,6 +19,8 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro import configs
+from repro.dist.collectives import collective_bytes, layer_trips
+from repro.dist.sharding import train_shardings
 from repro.models import build_batch_spec, init_cache, init_params
 from repro.models.transformer import RECOMPUTE, SAVE_PROJECTIONS
 from repro.train import OptConfig, init_opt_state, make_train_step
@@ -177,17 +179,15 @@ def test_qwen3_train_step_fits_one_v5e(one_chip, donate):
 
 def test_qwen3_sharded_train_step_compiles_for_2x2(topo):
     """The (data 2, model 2) train step that ``chip_smoke.py --four-chips``
-    runs: dryrun's shardings, partitioned over four chips."""
-    from repro.dist.sharding import set_mesh
-    from repro.launch.dryrun import sharded_train_step
+    runs, as its ``TrainState`` builds it: shardings from
+    ``train_shardings``, partitioned over four chips."""
     cfg, params = _qwen_abstract()
+    opt = OptConfig()
+    opt_state = jax.eval_shape(functools.partial(init_opt_state, opt), params)
+    batch = build_batch_spec(cfg, 8, 256)
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
-    set_mesh(mesh)
-    try:
-        fitted, opt_state, _ = sharded_train_step(
-            cfg, OptConfig(), mesh, params, build_batch_spec(cfg, 8, 256))
-    finally:
-        set_mesh(None)
+    fitted, _ = _fitted(cfg, opt, (params, opt_state, batch), True, mesh,
+                        train_shardings(mesh, params, opt_state, batch))
     assert fitted.policy is SAVE_PROJECTIONS
     compiled = fitted.compiled
     mem = compiled.memory_analysis()
@@ -211,8 +211,6 @@ def test_qwen3_1_7b_train_state_step_fits_2x2(topo, donate, seq):
     the four-chip cell; slot-held, as the launcher runs it).  At 4 x
     4,096 slot-held, saving would need more than a chip has, so the
     layers are recomputed, as before saving existed."""
-    from repro.dist.sharding import train_shardings
-    from repro.launch.dryrun import collective_bytes, layer_trips
     cfg = configs.get("qwen3-1.7b")
     params = jax.eval_shape(functools.partial(init_params, cfg),
                             jax.random.PRNGKey(0))

@@ -30,7 +30,7 @@ CHILD = textwrap.dedent('''
     from bench import span_stats
     from bench.reference import qwen3 as ref
     from bench.tests.tiny import TINY_MODEL
-    from repro.launch.dryrun import collective_bytes, layer_trips
+    from repro.dist.collectives import collective_bytes, layer_trips
     from repro.launch.mesh import make_mesh
     from repro.models.config import ModelConfig
     from repro.train import OptConfig, TrainState, shard_batch
