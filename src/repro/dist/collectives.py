@@ -30,6 +30,23 @@ def _group_size(line: str, default: int = 2) -> int:
     return default
 
 
+_INDEX_RE = re.compile(r"/\*index=\d+\*/")      # in long tuple types
+_HEADER_RE = re.compile(r"\s*(?:ENTRY\s+)?%([\w.-]+)\s+\(")
+_CHAIN_RE = re.compile(r'chain_id="(\d+)"')
+_RS_CALL_RE = re.compile(r"calls=%(all-reduce-scatter[\w.-]*)")
+
+
+def _result_bytes(rtype: str) -> int:
+    nbytes = 0
+    for dt, dims in _SHAPE_RE.findall(rtype):
+        n = 1
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        nbytes += n * _dtype_bytes(dt)
+    return nbytes
+
+
 def collective_bytes(hlo_text: str, while_mult: int = 1) -> dict:
     """Per-device *wire* bytes per collective kind, from partitioned HLO.
 
@@ -37,35 +54,55 @@ def collective_bytes(hlo_text: str, while_mult: int = 1) -> dict:
       all-reduce: 2(G-1)/G x R   all-gather: (G-1)/G x R_out
       reduce-scatter: (G-1) x R_out   all-to-all: (G-1)/G x R
       collective-permute: R
-    Ops inside while bodies (scan over layers) are multiplied by
-    ``while_mult`` (the scan trip count) — the body appears once in text
-    but executes every step.
+    A fusion that calls an ``%all-reduce-scatter`` computation (how the
+    TPU compiler fuses an all-reduce with the slice each chip keeps) is
+    one reduce-scatter of (G-1)/G x its all-reduce's R; that inner
+    all-reduce is not counted again.  A collective the compiler splits
+    over several asynchronous fusions carries one ``chain_id`` in each,
+    and counts once.  Ops inside while bodies (scan over layers) are
+    multiplied by ``while_mult`` (the scan trip count) — the body appears
+    once in text but executes every step.
     """
     out = {"all-gather": 0.0, "all-reduce": 0.0, "reduce-scatter": 0.0,
            "all-to-all": 0.0, "collective-permute": 0.0}
-    for line in hlo_text.splitlines():
+    comp, scatters, lines = "", {}, []
+    for line in _INDEX_RE.sub("", hlo_text).splitlines():
+        h = _HEADER_RE.match(line)
+        if h:
+            comp = h.group(1)
+            continue
         m = _OP_RE.search(line)
+        if comp.startswith("all-reduce-scatter"):
+            if m and m.group("kind") == "all-reduce":
+                scatters[comp] = (_result_bytes(m.group("rtype")),
+                                  _group_size(line))
+            continue
+        lines.append((line, m))
+    chains = set()
+    for line, m in lines:
+        mult = while_mult if "/while/" in line or "while" in line.split(
+            "metadata", 1)[-1] else 1
+        call = _RS_CALL_RE.search(line)
+        if call and call.group(1) in scatters:
+            nbytes, G = scatters[call.group(1)]
+            out["reduce-scatter"] += nbytes * (G - 1) / G * mult
+            continue
         if not m or m.group("start") == "-done":
             continue
         kind = m.group("kind")
-        shapes = _SHAPE_RE.findall(m.group("rtype"))
-        if not shapes:
-            shapes = _SHAPE_RE.findall(line.split("(")[0])
-        nbytes = 0
-        for dt, dims in shapes:
-            n = 1
-            for d in dims.split(","):
-                if d:
-                    n *= int(d)
-            nbytes += n * _dtype_bytes(dt)
+        chain = _CHAIN_RE.search(line)
+        if chain:
+            if (kind, chain.group(1)) in chains:
+                continue
+            chains.add((kind, chain.group(1)))
+        nbytes = _result_bytes(m.group("rtype")) \
+            or _result_bytes(line.split("(")[0])
         G = _group_size(line)
         ring = {"all-reduce": 2.0 * (G - 1) / G,
                 "all-gather": (G - 1) / G,
                 "reduce-scatter": float(G - 1),
                 "all-to-all": (G - 1) / G,
                 "collective-permute": 1.0}[kind]
-        mult = while_mult if "/while/" in line or "while" in line.split(
-            "metadata", 1)[-1] else 1
         out[kind] += nbytes * ring * mult
     return {k: int(v) for k, v in out.items()}
 

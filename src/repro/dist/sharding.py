@@ -18,9 +18,13 @@ Layout contract (see ``models/layers.py``):
 
 No state: every function takes the mesh it lays out for; ``shard_act``
 alone also takes None (one device), and then does nothing.  Batch and
-activations go over the data axes (``pod``, ``data``), as does the FSDP
-half of every weight rule; ``model`` carries tensor parallelism and the
-residual stream's sequence.
+activations go over the data axes (``pod``, ``data``); ``model`` carries
+the residual stream's sequence.  The dense projections (attention and
+MLP) are sharded on d_model alone, over the data axes and ``model``
+together: each chip holds a slice of every weight, a layer gathers it
+whole, and its gradient comes back to the owner in one reduce-scatter.
+The other weight rules put the FSDP half on the data axes and a second
+dim (experts, RG-LRU/RWKV widths, vocabulary) on ``model``.
 """
 
 from __future__ import annotations
@@ -81,16 +85,17 @@ def _dp_axes(mesh) -> tuple:
 #  parameter rules
 # ---------------------------------------------------------------------------
 # (path regex, trailing-dim tokens).  First match wins; tokens are
-# "dp" (FSDP axes), "model" (TP axis), or None (replicated).  Rules name
-# only the trailing dims — scan stacking pads None on the left.
+# "dp" (FSDP axes), "model" (TP axis), "all" (the dp axes and ``model``
+# together, on one dim), or None (replicated).  Rules name only the
+# trailing dims — scan stacking pads None on the left.
 _PARAM_RULES: tuple[tuple[str, tuple], ...] = (
-    # attention projections
-    (r"attn/wq$",            ("dp", "model", None)),
-    (r"attn/(wk|wv)$",       ("dp", "model", None)),
-    (r"attn/wo$",            ("model", None, "dp")),
-    # dense MLP
-    (r"mlp/(w_gate|w_up)$",  ("dp", "model")),
-    (r"mlp/w_down$",         ("model", "dp")),
+    # dense projections: d_model over every axis at once.  A one-dim tile
+    # lets XLA turn each layer's weight-gradient all-reduce + slice into
+    # one reduce-scatter; a two-dim tile blocks that rewrite.
+    (r"attn/(wq|wk|wv)$",    ("all", None, None)),
+    (r"attn/wo$",            (None, None, "all")),
+    (r"mlp/(w_gate|w_up)$",  ("all", None)),
+    (r"mlp/w_down$",         (None, "all")),
     # MoE: expert dim over model (expert parallelism), D FSDP-sharded
     (r"moe/(w_gate|w_up)$",  ("model", "dp", None)),
     (r"moe/w_down$",         ("model", None, "dp")),
@@ -126,6 +131,10 @@ def _path_str(path) -> str:
 def _resolve(token, mesh):
     if token == "dp":
         return _dp_axes(mesh) or None
+    if token == "all":
+        axes = _dp_axes(mesh) + (("model",) if "model" in dict(mesh.shape)
+                                 else ())
+        return axes or None
     return token
 
 

@@ -10,8 +10,9 @@ jitted step (color bump per epoch; the backup slot keeps each epoch's
 arrays, so the step does not donate them) -> epoch-batched
 checkpointing -> optional failure injection + recovery.  The default is
 the reduced smoke config; ``--full`` trains the published widths.  With
-``--mesh``, the weights are made on the mesh, FSDP over ``data`` and tensor
-parallel over ``model`` (``dist.sharding``), and the state stays there.
+``--mesh``, the weights are made on the mesh, sharded as ``dist.sharding``
+lays them out (the dense projections over every axis, on d_model), and
+the state stays there.
 """
 
 from __future__ import annotations

@@ -170,7 +170,7 @@ def place_train_state(mesh, opt: OptConfig, params):
 class _Variant(NamedTuple):
     jitted: Any
     saved: int                    # ``remat_saved_bytes``
-    wire: int | None              # ``collective_bytes``, on a mesh
+    wire: dict | None             # ``collective_bytes`` by kind, on a mesh
 
 
 class _OwnedStep:
@@ -204,10 +204,9 @@ class _OwnedStep:
                 self.microbatches)
             wire = None
             if self.mesh is not None:
-                wire = sum(collective_bytes(
+                wire = collective_bytes(
                     fitted.compiled.as_text(),
-                    while_mult=layer_trips(self.cfg, self.microbatches)
-                ).values())
+                    while_mult=layer_trips(self.cfg, self.microbatches))
             self.variants[key] = _Variant(fitted.jitted, fitted.saved, wire)
             self.latest[donate] = fitted.jitted
         return self.variants[key]
@@ -246,7 +245,8 @@ class TrainState:
     else 0), ``chips`` (the devices the state spans) and
     ``remat_saved_bytes`` (the bytes a chip keeps for the backward, 0 when
     the layer scan recomputes each layer), and on a mesh
-    ``collective_bytes``: the step's per-chip wire bytes, both worked out
+    ``collective_bytes`` and ``reduce_scatter_bytes``: the step's per-chip
+    wire bytes, all kinds and reduce-scatters alone, all worked out
     from each variant's compiled program at its first call.
     """
 
@@ -297,7 +297,9 @@ class TrainState:
                          "chips": self.chips,
                          "remat_saved_bytes": variant.saved}
                 if variant.wire is not None:
-                    stats["collective_bytes"] = variant.wire
+                    stats["collective_bytes"] = sum(variant.wire.values())
+                    stats["reduce_scatter_bytes"] = \
+                        variant.wire["reduce-scatter"]
             with span("train.dispatch", **stats):
                 params, opt_state, metrics = self._step(params, opt_state,
                                                         batch)

@@ -105,20 +105,22 @@ def test_activation_spec_odd_dims_replicate():
     assert activation_spec(m, (8, 24, 7)) == P(None, None, None)
 
 
-# The four-chip cell's layout: qwen3-1.7b on (data 2, model 2), FSDP over
-# `data`, tensor parallelism over `model`.
+# The four-chip cell's layout: qwen3-1.7b on (data 2, model 2), the dense
+# projections on d_model over `data` and `model` together (one dim, so each
+# layer's weight gradient reduce-scatters), the vocabulary over `model`.
+DM = ("data", "model")
 QWEN3_1_7B_2X2 = {
     "embed": P("model", "data"),
     "final_norm": P(None),
     "layers/attn/k_norm": P(None, None),
     "layers/attn/q_norm": P(None, None),
-    "layers/attn/wk": P(None, "data", "model", None),
-    "layers/attn/wo": P(None, "model", None, "data"),
-    "layers/attn/wq": P(None, "data", "model", None),
-    "layers/attn/wv": P(None, "data", "model", None),
-    "layers/mlp/w_down": P(None, "model", "data"),
-    "layers/mlp/w_gate": P(None, "data", "model"),
-    "layers/mlp/w_up": P(None, "data", "model"),
+    "layers/attn/wk": P(None, DM, None, None),
+    "layers/attn/wo": P(None, None, None, DM),
+    "layers/attn/wq": P(None, DM, None, None),
+    "layers/attn/wv": P(None, DM, None, None),
+    "layers/mlp/w_down": P(None, None, DM),
+    "layers/mlp/w_gate": P(None, DM, None),
+    "layers/mlp/w_up": P(None, DM, None),
     "layers/norm1": P(None, None),
     "layers/norm2": P(None, None),
 }
@@ -205,7 +207,11 @@ def test_tree_quantize_roundtrip_and_wire_bytes():
 # -------------------------------------------------------------- collectives
 # One HLO line per kind, with the per-device wire bytes the parser must
 # give at while_mult 10: result bytes x the ring factor of its group size
-# G (x10 inside a while body); a -done line carries no new bytes.
+# G (x10 inside a while body); a -done line carries no new bytes.  Three
+# cases hold more than one line: an all-reduce-scatter fusion counts once,
+# as a reduce-scatter of (G-1)/G x its all-reduce's bytes, and that inner
+# all-reduce not at all; the pieces of one chained collective count once;
+# a tuple's ``/*index=N*/`` comments hide none of its shapes.
 _COLLECTIVE_LINES = {
     "all-gather-in-while": (
         "all-gather",
@@ -242,6 +248,39 @@ _COLLECTIVE_LINES = {
         '%agd = bf16[16,256]{1,0} all-gather-done(%ags), '
         'metadata={op_name="jit(f)/agd"}',
         0),
+    "all-reduce-scatter-fusion": (
+        "reduce-scatter",
+        '%all-reduce-scatter.6 (input.7: bf16[6144,2048]) '
+        '-> bf16[6144,512] {\n'
+        '  %input.7 = bf16[6144,2048]{1,0} parameter(0)\n'
+        '  %all-reduce.81 = bf16[6144,2048]{1,0} all-reduce(%input.7), '
+        'channel_id=6, replica_groups={{0,1,2,3}}, to_apply=%add\n'
+        '  ROOT %ds = bf16[6144,512]{1,0} dynamic-slice(%all-reduce.81)\n'
+        '}\n\n'
+        'ENTRY %main (p: bf16[6144,2048]) -> bf16[6144,512] {\n'
+        '  %fusion.591 = bf16[6144,512]{1,0} fusion(%p), kind=kCustom, '
+        'calls=%all-reduce-scatter.6, '
+        'metadata={op_name="jit(f)/while/body/dot_general"}',
+        6144 * 2048 * 2 * (3 / 4) * 10),
+    "chained-all-gather": (
+        "all-gather",
+        '%all-gather.219 = bf16[2048,8,128]{2,0,1} all-gather(%p0), '
+        'replica_groups=[1,4]<=[4], dimensions={0}, '
+        'frontend_attributes={chain_id="6"}, '
+        'metadata={op_name="jit(f)/ag"}\n'
+        '  %all-gather.221 = bf16[2048,8,128]{2,0,1} all-gather(%p1), '
+        'replica_groups=[1,4]<=[4], dimensions={0}, '
+        'frontend_attributes={chain_id="6"}, '
+        'metadata={op_name="jit(f)/ag"}',
+        2048 * 8 * 128 * 2 * (3 / 4)),
+    "tuple-all-reduce": (
+        "all-reduce",
+        '%ar.62 = (bf16[64]{0}, bf16[64]{0}, bf16[64]{0}, bf16[64]{0}, '
+        'bf16[64]{0}, /*index=5*/bf16[64]{0}, bf16[64]{0}) '
+        'all-reduce(%a, %b, %c, %d, %e, /*index=5*/%f, %g), '
+        'replica_groups=[1,4]<=[4], to_apply=%add, '
+        'metadata={op_name="jit(f)/ar"}',
+        7 * 64 * 2 * 2 * (3 / 4)),
 }
 
 

@@ -235,3 +235,77 @@ def test_qwen3_1_7b_train_state_step_fits_2x2(topo, donate, seq):
     assert _peak(compiled) < HBM_BYTES
     wire = collective_bytes(compiled.as_text(), layer_trips(cfg))
     assert wire["all-gather"] > 0 and wire["all-reduce"] > 0
+
+
+def _computations(text: str) -> dict:
+    """Compiled HLO text by computation: name -> its instruction lines."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY\s+)?%([\w.-]+)\s+\(", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith("  %"):
+            comps[name].append(line)
+    return comps
+
+
+def _dims(hlo_type: str) -> list:
+    """Every bf16 shape in an HLO type, as tuples."""
+    return [tuple(map(int, d.split(",")))
+            for d in re.findall(r"bf16\[([\d,]+)\]", hlo_type)]
+
+
+def test_qwen3_1_7b_weight_gradients_reduce_scatter_on_2x2(topo):
+    """In the four-chip cell's donating 4 x 2,048 step each dense layer
+    weight lives on one dim over all four chips, so its gradient reaches
+    the owner through one reduce-scatter: seven ``all-reduce-scatter``
+    fusions each land a quarter of a weight, no other all-reduce carries
+    a whole one, the layer scan still saves its projections, and each
+    chip's arguments are its shards of the state and batch."""
+    cfg = configs.get("qwen3-1.7b")
+    params = jax.eval_shape(functools.partial(init_params, cfg),
+                            jax.random.PRNGKey(0))
+    opt = OptConfig()
+    opt_state = jax.eval_shape(functools.partial(init_opt_state, opt), params)
+    batch = build_batch_spec(cfg, 4, 2048)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    shardings = train_shardings(mesh, params, opt_state, batch)
+    fitted, _ = _fitted(cfg, opt, (params, opt_state, batch), True, mesh,
+                        shardings)
+    assert fitted.policy is SAVE_PROJECTIONS
+    # a chip's arguments: its shards, plus the tile padding of small leaves
+    shard_bytes = sum(math.prod(s.shard_shape(a.shape)) * a.dtype.itemsize
+                      for a, s in zip(jax.tree.leaves((params, opt_state,
+                                                       batch)),
+                                      jax.tree.leaves(shardings)))
+    padding = fitted.compiled.memory_analysis().argument_size_in_bytes \
+        - shard_bytes
+    assert 0 <= padding < 2**20
+
+    D, F, hd = cfg.d_model, cfg.d_ff, cfg.hd
+    H, Hkv = cfg.n_heads, cfg.n_kv_heads
+    weights = {(D, H, hd): 0, (D, Hkv, hd): 0, (H, hd, D): 2, (D, F): 0,
+               (F, D): 1}                   # whole shape -> d_model's dim
+    comps = _computations(fitted.compiled.as_text())
+    landed = []
+    for name, lines in comps.items():
+        for line in lines:
+            call = re.search(r"calls=%(all-reduce-scatter[\w.-]*)", line)
+            if call:
+                [inner] = [l for l in comps[call.group(1)]
+                           if " all-reduce(" in l]
+                whole = _dims(inner.split(" all-reduce(")[0])[0]
+                if whole in weights:
+                    landed.append((whole, _dims(line.split(" fusion(")[0])[0]))
+            elif not name.startswith("all-reduce-scatter") \
+                    and re.search(r" all-reduce(-start)?\(", line):
+                result = line.split(" all-reduce")[0]
+                assert not set(_dims(result)) & set(weights), line[:200]
+    quarter = []
+    for shape in [(D, H, hd), (D, Hkv, hd), (D, Hkv, hd), (H, hd, D),
+                  (D, F), (D, F), (F, D)]:
+        cut = list(shape)
+        cut[weights[shape]] //= 4
+        quarter.append((shape, tuple(cut)))
+    assert sorted(landed) == sorted(quarter)

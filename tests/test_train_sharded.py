@@ -4,8 +4,8 @@ the state over the four, keeps it there through both variants of its
 step, trains as the one-device ``TrainState`` and the plain float32
 reference do on the same seeded weights, keeps the ownership epoch
 (colour, backup slot, promotion), and puts ``chips``,
-``remat_saved_bytes`` and ``collective_bytes`` on ``train.dispatch`` only
-while the profiler records."""
+``remat_saved_bytes``, ``collective_bytes`` and ``reduce_scatter_bytes``
+on ``train.dispatch`` only while the profiler records."""
 
 import json
 import math
@@ -145,8 +145,9 @@ CHILD = textwrap.dedent('''
         text = fresh._owned.donating.lower(
             *fresh.state.read(), shard_batch(mesh, batches[0]))
         text = text.compile().as_text()
-        out["wire_bytes"] = sum(collective_bytes(
-            text, while_mult=layer_trips(mcfg)).values())
+        wire = collective_bytes(text, while_mult=layer_trips(mcfg))
+        out["wire_bytes"] = sum(wire.values())
+        out["rs_bytes"] = wire["reduce-scatter"]
         out["remat_saved"] = step_saved_bytes(mcfg, batches[0], mesh)
     print(json.dumps(out))
 ''')
@@ -204,4 +205,5 @@ def test_dispatch_stats_only_while_recording(run):
     assert run["remat_saved"] > 0
     assert run["stats_on"] == [{"donated": 1, "chips": 4,
                                 "remat_saved_bytes": run["remat_saved"],
-                                "collective_bytes": run["wire_bytes"]}] * 2
+                                "collective_bytes": run["wire_bytes"],
+                                "reduce_scatter_bytes": run["rs_bytes"]}] * 2
